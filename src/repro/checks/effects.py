@@ -210,7 +210,7 @@ def _walk_eqns(
 
 
 def _sub_jaxpr(eqn: Any) -> tuple[Any, Any]:
-    """Open jaxpr of a call-like eqn (pjit / remat / custom_*), or (None, None)."""
+    """Open jaxpr of a call-like eqn (jit / remat / custom_*), or (None, None)."""
     sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
     if sub is None:
         return None, None

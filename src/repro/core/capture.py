@@ -1,7 +1,7 @@
 """jaxpr → :class:`Graph` capture (the front half of ``graphi.compile``).
 
 ``capture(fn, *specs)`` traces ``fn`` with :func:`jax.make_jaxpr`, inlines
-``pjit``/``remat``/``custom_*`` call boundaries, fuses trivial data-movement
+``jit``/``remat``/``custom_*`` call boundaries, fuses trivial data-movement
 and elementwise chains into their consumers, and emits one :class:`OpNode`
 per surviving equation group.  Every node carries
 
@@ -36,10 +36,8 @@ __all__ = ["CapturedGraph", "capture"]
 # call-like primitives whose sub-jaxpr is semantically "just run the body":
 # inlined so the graph sees the real operator DAG, not opaque call nodes
 _INLINE_PRIMS = {
-    "pjit", "closed_call", "core_call", "xla_call",
-    "remat", "remat2", "checkpoint",
-    "custom_jvp_call", "custom_vjp_call",
-    "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
+    "jit", "closed_call", "remat2",
+    "custom_jvp_call", "custom_vjp_call", "custom_jvp_call_jaxpr",
 }
 _MAX_INLINE_DEPTH = 32
 
@@ -54,6 +52,8 @@ _MOVEMENT_PRIMS = {
 _GEMM_PRIMS = {"dot_general"}
 _CONV_PRIMS = {"conv_general_dilated"}
 _LOOP_PRIMS = {"scan", "while", "fori_loop"}
+# a hand-written kernel: its own node, never folded into a neighbour
+_KERNEL_PRIMS = {"pallas_call"}
 _REDUCE_PREFIXES = ("reduce_", "cum", "arg")
 
 
@@ -64,6 +64,8 @@ def _kind_of(prim_name: str) -> str:
         return "conv"
     if prim_name in _LOOP_PRIMS:
         return "scan"
+    if prim_name in _KERNEL_PRIMS:
+        return "kernel"
     if prim_name == "cond":
         return "control"
     if prim_name in _MOVEMENT_PRIMS:
@@ -126,6 +128,10 @@ def _eqn_flops(eqn: Any) -> float:
         if body is None:
             return 0.0
         return trips * sum(_eqn_flops(e) for e in body.eqns)
+    if prim == "pallas_call":
+        # the kernel body (over block refs) runs once per grid step
+        trips = float(np.prod(eqn.params["grid_mapping"].grid))
+        return trips * sum(_eqn_flops(e) for e in eqn.params["jaxpr"].eqns)
     sub, _ = _sub_jaxpr(eqn)
     if sub is not None:
         return sum(_eqn_flops(e) for e in sub.eqns)
@@ -186,7 +192,7 @@ def _flatten(eqns, sub_map: dict, constenv: dict, depth: int = 0) -> list:
                         if isinstance(sub_ov, jex.Var) else sub_ov
                     )
                 continue
-        fresh = [jex.Var("", ov.aval) for ov in eqn.outvars]
+        fresh = [jex.Var(ov.aval) for ov in eqn.outvars]
         for ov, fv in zip(eqn.outvars, fresh):
             sub_map[ov] = fv
         out.append(eqn.replace(invars=invars, outvars=fresh))

@@ -9,11 +9,11 @@ replicas bit-exactly (greedy decode of ``prompt + emitted``).  See
 """
 from repro.fleet.faults import FaultInjector, FaultSpec, corrupt_lease_release
 from repro.fleet.router import Router
-from repro.fleet.supervisor import Fleet, FleetConfig, FleetRequest
+from repro.fleet.supervisor import Fleet, FleetConfig, FleetRequest, WorkerStartupError
 from repro.fleet.worker import ToyEngine, build_engine, toy_next_token, worker_main
 
 __all__ = [
-    "Fleet", "FleetConfig", "FleetRequest", "Router",
+    "Fleet", "FleetConfig", "FleetRequest", "Router", "WorkerStartupError",
     "FaultInjector", "FaultSpec", "corrupt_lease_release",
     "ToyEngine", "build_engine", "toy_next_token", "worker_main",
 ]

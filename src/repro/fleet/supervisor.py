@@ -20,6 +20,10 @@ Failure handling — the tentpole contract:
   resumed stream bit-identical, which :meth:`_on_token` asserts by index;
 * the dead slot respawns with a bumped generation (bounded by
   ``max_restarts``), and the router forgets its prefix affinity.
+
+A worker that exits before it sends ``ready`` could not build its engine,
+and a respawn would fail the same way: the fleet stops and raises
+:class:`WorkerStartupError` with the worker's exit code and error.
 """
 from __future__ import annotations
 
@@ -38,7 +42,19 @@ from repro.fleet.worker import worker_main
 
 _log = logging.getLogger(__name__)
 
-__all__ = ["Fleet", "FleetConfig", "FleetRequest"]
+__all__ = ["Fleet", "FleetConfig", "FleetRequest", "WorkerStartupError"]
+
+
+class WorkerStartupError(RuntimeError):
+    """A replica exited before it was ready (its engine failed to build)."""
+
+    def __init__(self, wid: int, exitcode: int, error: str | None):
+        self.wid = wid
+        self.exitcode = exitcode
+        self.error = error
+        super().__init__(
+            f"fleet: worker {wid} exited with code {exitcode} before it was "
+            f"ready: {error or 'no error reported'}")
 
 
 @dataclass(frozen=True)
@@ -83,6 +99,7 @@ class _Worker:
         self.conn = conn
         self.generation = generation
         self.ready = False
+        self.error: str | None = None
         self.last_msg = time.monotonic()
         self.inflight: dict[int, FleetRequest] = {}
 
@@ -284,6 +301,8 @@ class Fleet:
             self._event("ready", w.wid, f"pid {msg['pid']}")
         elif kind == "hb":
             pass
+        elif kind == "error":
+            w.error = msg["error"]
         elif kind == "tokens":
             for rid, token, index, done in msg["items"]:
                 if index >= 0:
@@ -338,6 +357,12 @@ class Fleet:
                 self._handle(w, w.conn.recv())
         except (EOFError, BrokenPipeError, OSError):
             pass
+        if not w.ready:
+            w.proc.join(timeout=self.cfg.term_grace_s)
+            if w.proc.exitcode is not None:        # died while starting up
+                w.conn.close()
+                self.close()
+                raise WorkerStartupError(wid, w.proc.exitcode, w.error)
         if w.proc.is_alive():
             w.proc.terminate()                     # SIGTERM
             w.proc.join(timeout=self.cfg.term_grace_s)

@@ -21,6 +21,7 @@ supervisor -> worker
 
 worker -> supervisor
     ``{"type": "ready", "pid"}``           engine built, serving
+    ``{"type": "error", "error"}``         engine build failed; exiting
     ``{"type": "hb", "inflight", "done_tokens"}``  liveness beacon
     ``{"type": "tokens", "items": [(rid, token, index, done), ...]}``
         one decode step's worth of tokens (batched: one pickle round per
@@ -223,7 +224,13 @@ def worker_main(worker_id: int, conn, engine_spec: dict,
     Drives the engine one step at a time, streaming every token as it is
     decoded; idle polls block briefly on the pipe so a quiet worker costs
     ~0 CPU.  Heartbeats ride the main loop by design (see module docs)."""
-    engine = build_engine(engine_spec)
+    try:
+        engine = build_engine(engine_spec)
+    except BaseException as e:
+        # say why before dying: the supervisor stops the fleet instead of
+        # respawning a replica that cannot start
+        conn.send({"type": "error", "error": f"{type(e).__name__}: {e}"})
+        raise
     conn.send({"type": "ready", "pid": os.getpid()})
     last_hb = 0.0
     mute_until = 0.0

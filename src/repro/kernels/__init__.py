@@ -4,13 +4,12 @@ Each kernel ships as a subpackage: ``kernel.py`` (pl.pallas_call + explicit
 BlockSpec VMEM tiling), ``ops.py`` (the jit-able wrapper with shape policy),
 ``ref.py`` (the pure-jnp oracle every test asserts against).
 
-On this CPU-only container the kernels execute through ``interpret=True``
-(the kernel body runs in Python per grid step).  ``default_interpret()``
-resolves the mode from the backend; the models call the pure-jnp paths by
-default (same math as ref.py) and switch to the kernels when
-``REPRO_USE_PALLAS=1`` or a TPU backend is present — interpret-mode kernels
-inside a 40-cell dry-run would only slow compilation without changing the
-lowered collectives.
+Off a TPU the kernels execute through ``interpret=True`` (the kernel body
+lowers to plain XLA).  ``default_interpret()`` resolves the mode from the
+backend; the models call the pure-jnp paths by default (same math as
+ref.py) and switch to the kernels when ``REPRO_USE_PALLAS=1`` or a TPU
+backend is present — interpret-mode kernels inside a 40-cell dry-run would
+only slow compilation without changing the lowered collectives.
 """
 from __future__ import annotations
 

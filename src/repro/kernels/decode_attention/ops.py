@@ -39,7 +39,6 @@ def decode_attention(
     return out[:, None] if squeeze else out
 
 
-@partial(jax.jit, static_argnames=("window", "use_kernel", "interpret"))
 def paged_decode_attention(
     q: jax.Array,           # [B, 1, Hq, hd] (model layout) or [B, Hq, hd]
     k_pages: jax.Array,     # [P, ps, Hkv, hd] global page pool
@@ -57,22 +56,35 @@ def paged_decode_attention(
     request's pages reconstruct its linear KV cache without the cache ever
     existing contiguously.  Two paths:
 
-    - the pure-jnp gather path (default off-TPU) — this is what the serving
-      decode graph captures: an explicit ``pages[table]`` gather plus the
-      same position-table-masked softmax as :func:`decode_attention`, so
-      graphi fuses the gather into the attention group and ``StaticHostPlan``
-      replay sees a fixed-shape movement op;
+    - the pure-jnp gather path (default off-TPU): an explicit
+      ``pages[table]`` gather plus the same position-table-masked softmax
+      as :func:`decode_attention`, so graphi fuses the gather into the
+      attention group and ``StaticHostPlan`` replay sees a fixed-shape
+      movement op;
     - the Pallas kernel (``REPRO_USE_PALLAS=1`` or real TPU), whose
       scalar-prefetch BlockSpec index map chases the page table directly.
+
+    The path is resolved here, outside the jit, so it is part of the jit's
+    cache key: flipping ``REPRO_USE_PALLAS`` never reuses the other path's
+    trace.
     """
     from repro.kernels import kernels_enabled
-
-    from .kernel import paged_decode_attention_kernel_call
 
     if use_kernel is None:
         use_kernel = kernels_enabled()
     if interpret is None:
         interpret = default_interpret()
+    return _paged_decode_attention(q, k_pages, v_pages, page_table, q_pos,
+                                   window=window, use_kernel=use_kernel,
+                                   interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("window", "use_kernel", "interpret"))
+def _paged_decode_attention(q, k_pages, v_pages, page_table, q_pos, *,
+                            window: int | None, use_kernel: bool,
+                            interpret: bool) -> jax.Array:
+    from .kernel import paged_decode_attention_kernel_call
+
     squeeze = q.ndim == 4
     if squeeze:
         q = q[:, 0]

@@ -11,10 +11,15 @@ on-pod); the ``pod`` axis crosses DCN and only carries gradient
 all-reduces. The same constructor scales to any pod count — 1000+ chips is
 ``multi_pod`` with more pods (e.g. (8, 16, 16) = 2048 chips); nothing in the
 sharding rules depends on the pod count.
+
+Every mesh is built with ``Auto`` axes: the model code places activations
+with ``with_sharding_constraint`` (``dist.sharding.shard``), which refuses
+``Explicit`` axes, ``jax.make_mesh``'s default.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "describe_mesh"]
 
@@ -22,12 +27,13 @@ __all__ = ["make_production_mesh", "make_mesh", "describe_mesh"]
 def make_production_mesh(*, multi_pod: bool = False, n_pods: int = 2):
     shape = (n_pods, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """General constructor for experiments (perf pass tries other splits)."""
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *, devices=None):
+    """An ``Auto``-axis mesh over ``devices`` (default: all of them)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def describe_mesh(mesh) -> str:

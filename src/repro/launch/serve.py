@@ -19,6 +19,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import transformer
 from repro.serve.engine import ContinuousEngine, Request, ServeConfig, ServeEngine
 from repro.serve.paged import PagedConfig, PagedEngine
@@ -249,8 +250,13 @@ def main() -> int:
     if args.replicas > 1:
         return serve_fleet(args)
 
+    enable_compile_cache()
+
     cfg = get_config(args.arch, smoke=args.smoke)
-    params = transformer.init_params(cfg, jax.random.key(0))
+    # jitted: eager init holds float32 temporaries of the largest weights
+    # beside the bf16 ones, and a 4 B-param model then overflows a 16 GB chip
+    params = jax.jit(transformer.init_params, static_argnums=0)(
+        cfg, jax.random.key(0))
     prompt_lens = [int(x) for x in str(args.prompt_len).split(",")]
     scfg = ServeConfig(
         max_batch=args.max_batch,

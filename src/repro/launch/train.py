@@ -1,9 +1,9 @@
 """Training CLI: ``python -m repro.launch.train --arch gemma-2b [--smoke]``.
 
 Wires the full stack: config -> synthetic data pipeline -> sharded train
-step (pjit) -> fault-tolerant Trainer (checkpoint/restart, straggler
-watchdog).  On this CPU box, ``--smoke`` (reduced config, 1 device) is the
-runnable path; the full configs are exercised via ``launch.dryrun``.
+step (jit) -> fault-tolerant Trainer (checkpoint/restart, straggler
+watchdog).  ``--smoke`` runs a reduced config; ``--mesh 2x2`` shards the
+state and the step over a (data, model) mesh of the local devices.
 
     PYTHONPATH=src python -m repro.launch.train --arch gemma-2b --smoke \
         --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
@@ -11,15 +11,70 @@ runnable path; the full configs are exercised via ``launch.dryrun``.
 from __future__ import annotations
 
 import argparse
+from typing import Any, Callable
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
-from repro.configs.base import ShapeSpec, get_config
+from repro.configs.base import ModelConfig, ShapeSpec, get_config
 from repro.data import DataConfig, SyntheticTokens
-from repro.dist.sharding import MeshCtx, batch_axes, state_pspecs, use_mesh
+from repro.dist.sharding import (MeshCtx, batch_axes, batch_pspecs, state_pspecs,
+                                 use_mesh)
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
+from repro.optim.adamw import AdamWConfig
 from repro.train.step import TrainStepConfig, init_train_state, make_train_step
 from repro.train.trainer import Trainer, TrainerConfig
+
+
+def _named(mesh, specs: Any) -> Any:
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda s: isinstance(s, P))
+
+
+def state_shardings(cfg: ModelConfig, adamw: AdamWConfig, mesh) -> Any:
+    """Train-state shardings on ``mesh``: the Megatron rules of
+    ``state_pspecs`` plus ZeRO-3 over ``data``, so params and both AdamW
+    moments are split over every device."""
+    shapes = jax.eval_shape(
+        lambda k: init_train_state(cfg, k, adamw), jax.random.key(0))
+    return _named(mesh, state_pspecs(cfg, shapes, mesh, fsdp=True))
+
+
+def init_state(cfg: ModelConfig, key, adamw: AdamWConfig, mesh=None) -> dict:
+    """The train state; on a mesh it is created already sharded (the init is
+    jitted with ``out_shardings``), never whole on one device."""
+    if mesh is None:
+        return init_train_state(cfg, key, adamw)
+    init = jax.jit(lambda k: init_train_state(cfg, k, adamw),
+                   out_shardings=state_shardings(cfg, adamw, mesh))
+    return init(key)
+
+
+def make_run_step(cfg: ModelConfig, tcfg: TrainStepConfig, mesh=None, *,
+                  global_batch: int, seq_len: int) -> Callable:
+    """``run_step(state, batch) -> (state, metrics)``; on a mesh the batch is
+    split over its data axes and the model's ``shard`` calls resolve
+    against the mesh while the step traces."""
+    step = make_train_step(cfg, tcfg)
+    if mesh is None:
+        return jax.jit(step, donate_argnums=(0,))
+    ctx = MeshCtx(mesh, batch_axes(mesh, global_batch))
+    batch_spec = jax.ShapeDtypeStruct((global_batch, seq_len), jnp.int32)
+    batch_sh = _named(mesh, batch_pspecs(
+        {"tokens": batch_spec, "labels": batch_spec}, mesh, global_batch))
+    state_sh = state_shardings(cfg, tcfg.adamw, mesh)
+    step_jit = jax.jit(step, donate_argnums=(0,),
+                       in_shardings=(state_sh, batch_sh),
+                       out_shardings=(state_sh, NamedSharding(mesh, P())))
+
+    def run_step(state, batch):
+        with use_mesh(ctx):
+            return step_jit(state, batch)
+
+    return run_step
 
 
 def main() -> int:
@@ -56,6 +111,7 @@ def main() -> int:
                         "(simulated on this sim-backend path)")
     args = p.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     shape = ShapeSpec("cli", args.seq, args.batch, "train")
 
@@ -81,8 +137,6 @@ def main() -> int:
         if args.dump_trace:
             print(exe.render_trace(fmt=args.dump_trace))
 
-    from repro.optim.adamw import AdamWConfig
-
     tcfg = TrainStepConfig(
         microbatches=args.microbatches,
         remat=not args.smoke,
@@ -90,29 +144,13 @@ def main() -> int:
         total_steps=args.steps,
         warmup_steps=max(1, args.steps // 20),
     )
-    key = jax.random.key(0)
-    state = init_train_state(cfg, key, tcfg.adamw)
-    step = make_train_step(cfg, tcfg)
-
     mesh = None
     if args.mesh:
         dims = tuple(int(d) for d in args.mesh.split("x"))
-        names = ("data", "model")[: len(dims)]
-        mesh = jax.make_mesh(dims, names)
-        specs = state_pspecs(cfg, jax.eval_shape(lambda: state), mesh)
-        shardings = jax.tree.map(
-            lambda s: jax.sharding.NamedSharding(mesh, s), specs,
-            is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec),
-        )
-        state = jax.device_put(state, shardings)
-        ctx = MeshCtx(mesh, batch_axes(mesh, args.batch))
-        step_jit = jax.jit(step, donate_argnums=(0,))
-
-        def run_step(s, b):
-            with use_mesh(ctx):
-                return step_jit(s, b)
-    else:
-        run_step = jax.jit(step, donate_argnums=(0,))
+        mesh = make_mesh(dims, ("data", "model")[: len(dims)])
+    state = init_state(cfg, jax.random.key(0), tcfg.adamw, mesh)
+    run_step = make_run_step(cfg, tcfg, mesh, global_batch=args.batch,
+                             seq_len=args.seq)
 
     data = SyntheticTokens(DataConfig(
         vocab_size=cfg.vocab_size, seq_len=args.seq,

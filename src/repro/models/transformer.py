@@ -125,11 +125,11 @@ def init_params(cfg: ModelConfig, key) -> dict:
     kinds = cfg.layer_kinds()
     layer_keys = keys[2 : 2 + cfg.n_layers]
     if cfg.scan_layers and cfg.is_homogeneous:
-        stacked = [
-            _init_layer(layer_keys[i], cfg, kinds[i], dtype, cross=cfg.cross_attention)
-            for i in range(cfg.n_layers)
-        ]
-        params["layers"] = jax.tree.map(lambda *xs: jnp.stack(xs), *stacked)
+        # vmapped, not stacked from per-layer copies: the stack would hold
+        # every layer twice at its peak (2 x 7.9 GB for h2o-danube3-4b)
+        params["layers"] = jax.vmap(
+            lambda k: _init_layer(k, cfg, kinds[0], dtype, cross=cfg.cross_attention)
+        )(layer_keys)
     else:
         params["layers"] = [
             _init_layer(layer_keys[i], cfg, kinds[i], dtype, cross=cfg.cross_attention)

@@ -1,4 +1,4 @@
-"""Training: step functions (pjit) and the fault-tolerant trainer loop."""
+"""Training: step functions (jit) and the fault-tolerant trainer loop."""
 from .step import TrainStepConfig, init_train_state, make_train_step
 
 __all__ = ["TrainStepConfig", "init_train_state", "make_train_step"]

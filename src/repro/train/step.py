@@ -1,4 +1,4 @@
-"""The pjit train step: loss -> grad -> AdamW, with microbatch gradient
+"""The jitted train step: loss -> grad -> AdamW, with microbatch gradient
 accumulation (``lax.scan``) and per-layer remat.
 
 State layout (a flat dict so dist/sharding.state_pspecs can rule-match):

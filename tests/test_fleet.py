@@ -12,7 +12,7 @@ import time
 import pytest
 
 from repro.fleet import (Fleet, FleetConfig, FaultInjector, FaultSpec, Router,
-                         corrupt_lease_release)
+                         WorkerStartupError, corrupt_lease_release)
 from repro.fleet.worker import ToyEngine, toy_next_token
 
 VOCAB = 101
@@ -204,6 +204,21 @@ def test_fleet_restart_budget_exhaustion_raises():
             fleet.submit([1, 2], 50)
             inj = FaultInjector([FaultSpec(kind="kill", at_tokens=2)], seed=0)
             fleet.run(timeout_s=60, injector=inj)
+
+
+def test_fleet_stops_when_a_worker_cannot_start():
+    """An engine that raises in its constructor is a start-up fault, not a
+    crash to fail over: the fleet stops with the worker's exit code and
+    error instead of burning the restart budget on respawns."""
+    cfg = FleetConfig(n_workers=2, engine={"kind": "paged", "arch": "no-such-arch"},
+                      heartbeat_s=0.05, term_grace_s=0.3)
+    fleet = Fleet(cfg)
+    with pytest.raises(WorkerStartupError) as err:
+        fleet.wait_ready(timeout_s=120)
+    assert err.value.exitcode == 1
+    assert "no_such_arch" in err.value.error
+    assert fleet.n_restarts == 0
+    assert fleet.stats()["n_workers"] == 0          # every replica stopped
 
 
 # ---------------------------------------------------------------------------
