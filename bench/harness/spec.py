@@ -1,0 +1,59 @@
+"""The benchmark's description: ``BENCHMARK.json`` at the checkout root, and
+the files it names by convention.
+
+A cell (``workloads`` entry) names a configuration and a traffic mix.  The
+configuration is ``configs/<config>.json`` (or the ``file`` its entry gives),
+the traffic mix ``traffic/<traffic>.json``, and every metric a reader
+``metrics/<name>.py`` -- all under ``bench/``.  Adding a cell or a metric
+therefore means adding files and entries, never editing one.
+"""
+from __future__ import annotations
+
+import json
+from dataclasses import dataclass
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+
+
+@dataclass(frozen=True)
+class Cell:
+    name: str
+    chips: int
+    config: dict          # the configuration file's contents
+    traffic: dict         # the traffic file's contents
+    end_to_end: list      # metric entries this cell reports with --trace 0
+    per_layer: list       # ... and with --trace 1
+    run_seconds: int
+    limits: dict          # limits/<cell>.json: what ``correct`` compares
+
+
+def load_json(path: Path) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def _applies(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def load_cell(name: str, root: Path = ROOT) -> Cell:
+    """The cell ``name`` with its configuration, traffic and metrics.
+    Raises ``KeyError`` for an unknown cell and ``FileNotFoundError`` when a
+    file it names is missing."""
+    spec = load_json(root / "BENCHMARK.json")
+    cells = {w["name"]: w for w in spec["workloads"]}
+    if name not in cells:
+        raise KeyError(f"no workload {name!r} in BENCHMARK.json "
+                       f"(have {sorted(cells)})")
+    w = cells[name]
+    configs = {c["name"]: c for c in spec["configs"]}
+    config = load_json(root / configs[w["config"]]["file"])
+    traffic = load_json(root / "bench" / "traffic" / f"{w['traffic']}.json")
+    return Cell(
+        name=name, chips=int(w["chips"]), config=config, traffic=traffic,
+        end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
+        per_layer=[m for m in spec["per_layer"] if _applies(m, name)],
+        run_seconds=int(spec["run_seconds"]),
+        limits=load_json(root / "bench" / "limits" / f"{name}.json"),
+    )
