@@ -31,6 +31,7 @@ from typing import Any, Callable, Mapping
 
 from .graph import Graph
 from .simulate import TraceEvent
+from .trace import PLAN_RUN_SPAN, name_thread, node_span_names, span
 
 __all__ = ["DeadlineExceeded", "ExecutorPool", "HostScheduler", "HostRunResult"]
 
@@ -202,6 +203,7 @@ class ExecutorPool:
         self.close()
 
     def _worker(self, ex: int) -> None:
+        name_thread(f"graphi-exec-{ex}")
         while True:
             item = self._buffers[ex].get()
             if item is None:
@@ -227,6 +229,11 @@ class ExecutorPool:
 
 def _input_lookup(inputs: Mapping[str, Any], name: str) -> Any:
     return inputs[name]
+
+
+def _call_in_span(span_name: str, fn: Callable[..., Any], *args: Any) -> Any:
+    with span(span_name):
+        return fn(*args)
 
 
 @dataclass
@@ -283,6 +290,7 @@ class HostScheduler:
         self._ready0 = sorted(self._entry[n] for n in names if self._indeg0[n] == 0)
         self._total = len(graph)
         self._graph_version = graph.version
+        self._node_spans = dict(zip(names, node_span_names(names)))
 
     def run(
         self,
@@ -291,6 +299,10 @@ class HostScheduler:
         pool: Any = None,
         deadline: float | None = None,
     ) -> HostRunResult:
+        with span(PLAN_RUN_SPAN):
+            return self._run(inputs, pool, deadline)
+
+    def _run(self, inputs, pool, deadline) -> HostRunResult:
         g = self.graph
         if g.version != self._graph_version:
             # the per-graph immutables above were hoisted to __init__; a
@@ -305,6 +317,7 @@ class HostScheduler:
         indeg = dict(self._indeg0)
         entry = self._entry
         successors = g.successors
+        node_spans = self._node_spans
 
         ready: list[tuple[float, int, str]] = list(self._ready0)  # sorted => heap
 
@@ -375,7 +388,8 @@ class HostScheduler:
                     # relayed like any other op failure
                     task: Any = partial(_input_lookup, inputs, name)
                 else:
-                    task = partial(node.fn, *(results[d] for d in node.deps))
+                    task = partial(_call_in_span, node_spans[name], node.fn,
+                                   *(results[d] for d in node.deps))
                 inflight[ex] += 1
                 if inflight[ex] < depth:
                     heapq.heappush(idle, (inflight[ex], pool.qsize(ex), ex))

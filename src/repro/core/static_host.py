@@ -40,7 +40,7 @@ import itertools
 import queue
 import sys
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 from threading import Lock
 from typing import Any, Callable, Mapping
@@ -49,6 +49,7 @@ from .engine import _ERR, DeadlineExceeded, ExecutorPool, HostRunResult
 from .graph import Graph, GraphValidationError
 from .scheduler import Schedule
 from .simulate import TraceEvent
+from .trace import PLAN_RUN_SPAN, node_span_names, span
 
 __all__ = ["StaticHostPlan", "compile_host_plan", "layered_graph"]
 
@@ -179,6 +180,11 @@ class StaticHostPlan:
     # plan froze — "cpf", or a searched winner such as "cpf-perturb"
     policy: str = "cpf"
     seed: int = 0
+    # id -> "repro.plan.node/<name>", the span around each node call
+    node_spans: tuple[str, ...] = field(init=False, repr=False, compare=False)
+
+    def __post_init__(self) -> None:
+        object.__setattr__(self, "node_spans", node_span_names(self.names))
 
     @property
     def n_ops(self) -> int:
@@ -217,7 +223,10 @@ class StaticHostPlan:
         is raised naming whatever ops are still on executor threads, so a
         hung op frees this run's lease instead of wedging it forever.
         """
-        inputs = inputs or {}
+        with span(PLAN_RUN_SPAN):
+            return self._run(inputs or {}, pool, collect_trace, deadline)
+
+    def _run(self, inputs, pool, collect_trace, deadline) -> HostRunResult:
         if self.graph.version != self.graph_version:
             # same staleness guard as HostScheduler.run: the frozen integer
             # programs would silently skip any node added since compile
@@ -360,6 +369,7 @@ def _run_segment(
     length, or on a poison id after another segment failed.
     """
     fns = plan.fns
+    spans = plan.node_spans
     arg_ids = plan.arg_ids
     succ_ids = plan.succ_ids
     owner = plan.owner
@@ -383,7 +393,8 @@ def _run_segment(
         try:
             if collect_trace:
                 t0 = time.perf_counter() - t_origin
-            results[i] = fns[i](*[results[d] for d in arg_ids[i]])
+            with span(spans[i]):
+                results[i] = fns[i](*[results[d] for d in arg_ids[i]])
         except BaseException as exc:  # noqa: BLE001 — relayed to the client
             state.fail(exc, plan.names[i], e)
             return remaining

@@ -43,6 +43,7 @@ from typing import Any, Callable, Mapping
 from repro.core.cost_model import KNL7250, HardwareModel
 from repro.core.engine import DeadlineExceeded, ExecutorPool
 from repro.core.graph import Graph
+from repro.core.trace import span
 
 __all__ = [
     "AdmissionRejected",
@@ -54,6 +55,8 @@ __all__ = [
     "graph_signature",
     "set_default_runtime",
 ]
+
+_LEASE_WAIT_SPAN = "repro.runtime.lease_wait"
 
 
 class AdmissionRejected(RuntimeError):
@@ -316,6 +319,9 @@ class _Admission:
         self.n_bad_releases = 0
         self.n_leaks_reclaimed = 0
         self.n_shed = 0
+        # acquires that had to block, and the seconds they blocked in all
+        self.n_lease_waits = 0
+        self.lease_wait_s = 0.0
 
     def attach_probe(self, probe: Callable[[], list]) -> None:
         """Wire the pool's ``current_tasks`` snapshot in (set once, at pool
@@ -399,7 +405,7 @@ class _Admission:
                 return self._queue[0] is ticket and len(self._free) >= width
 
             try:
-                ok = self._cond.wait_for(ready, timeout=timeout)
+                ok = ready() or self._wait(ready, timeout)
             except BaseException:
                 # e.g. KeyboardInterrupt mid-wait: an orphaned ticket at the
                 # queue head would wedge strict-FIFO admission forever
@@ -431,6 +437,16 @@ class _Admission:
             # the next waiter may already be satisfiable (narrower request)
             self._cond.notify_all()
             return ids
+
+    def _wait(self, ready: Callable[[], bool], timeout: float | None) -> bool:
+        """Block until ``ready()`` (lock held), counted and spanned."""
+        t0 = time.perf_counter()
+        try:
+            with span(_LEASE_WAIT_SPAN):
+                return self._cond.wait_for(ready, timeout=timeout)
+        finally:
+            self.n_lease_waits += 1
+            self.lease_wait_s += time.perf_counter() - t0
 
     def release(self, ids: tuple[int, ...], held: float | None = None) -> None:
         with self._cond:
@@ -781,6 +797,8 @@ class Runtime:
             "bad_releases": adm.n_bad_releases,
             "leaks_reclaimed": adm.n_leaks_reclaimed,
             "shed": adm.n_shed,
+            "n_lease_waits": adm.n_lease_waits,
+            "lease_wait_s": adm.lease_wait_s,
             "stuck_close": len(self._pool.stuck_executors) if self._pool else 0,
         }
 

@@ -93,12 +93,15 @@ class _SamplerMixin:
     scfg: ServeConfig
     _key: jax.Array
 
-    def _sample(self, logits) -> np.ndarray:
+    def _sample_on_device(self, logits) -> jax.Array:
+        """Dispatch sampling; the tokens stay on the device."""
         key = None
         if self.scfg.temperature > 0:
             self._key, key = jax.random.split(self._key)
-        toks = sample_tokens(logits, self.cfg.vocab_size, self.scfg.temperature, key)
-        return np.asarray(toks, np.int32)
+        return sample_tokens(logits, self.cfg.vocab_size, self.scfg.temperature, key)
+
+    def _sample(self, logits) -> np.ndarray:
+        return np.asarray(self._sample_on_device(logits), np.int32)
 
 
 class ServeEngine(_SamplerMixin):

@@ -58,8 +58,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import trace
 from repro.core.cost_model import KNL7250, HardwareModel
 from repro.core.engine import ExecutorPool
+from repro.core.trace import span
 from repro.models import transformer
 from repro.runtime import Runtime, default_runtime
 from repro.serve.engine import Request, ServeConfig, _SamplerMixin, _validate_submit
@@ -67,6 +69,24 @@ from repro.serve.step import (make_paged_decode_step, make_prefill_chunk_step,
                               sample_tokens)
 
 __all__ = ["PagedConfig", "PagePool", "PagedEngine", "PoolExhausted"]
+
+# spans of one engine step (core/trace.py); all on the thread that calls
+# step(), except repro.paged.chunk, which an overlapped step runs on its
+# prefill thread
+_STEP = "repro.paged.step"
+_ADMIT = "repro.paged.admit"              # pending -> slots, prefix match
+_COW_COPY = "repro.paged.cow_copy"        # dispatch of a copy-on-write
+_ALLOC = "repro.paged.alloc"              # chunk and boundary pages
+_LEASE = "repro.paged.lease"              # the step's executor lease
+_UPLOAD = "repro.paged.upload"            # lengths, table, tokens -> device
+_DECODE = "repro.paged.decode"            # the decode graph's host run
+_SAMPLE = "repro.paged.sample"            # dispatch of the sampler
+_READBACK = "repro.paged.readback"        # wait for the sampled tokens
+_EMIT = "repro.paged.emit"                # tokens to requests, retirement
+_CHUNK = "repro.paged.chunk"              # one prefill chunk's host run
+_CHUNK_JOIN = "repro.paged.chunk_join"    # wait for the prefill thread
+_INSERT_CHUNK = "repro.paged.insert_chunk"  # dispatch of a chunk's K/V insert
+_PREFILL_THREAD = "paged-prefill"         # its OS name, seen by the profiler
 
 
 class PoolExhausted(RuntimeError):
@@ -378,6 +398,7 @@ class PagedEngine(_SamplerMixin):
         self.n_shared_pages = 0
         self.n_cow_copies = 0
         self.n_evictions = 0
+        self._process_trace = trace.install()
 
         # warm every per-step code path against throwaway state
         warm_pages = jax.tree.map(jnp.zeros_like, self._pages)
@@ -386,24 +407,29 @@ class PagedEngine(_SamplerMixin):
                       "pages": warm_pages}
         toks0 = jnp.asarray(self._tokens)
         with self._step_pool() as wpool:
-            logits, _ = self._run_exe(
-                self._decode_exe, (params, warm_cache, toks0), pool=wpool)
+            # only the logits: a warm run's output pool is one more copy
+            # of the pool, and the device has no room to keep it
+            logits = self._run_exe(
+                self._decode_exe, (params, warm_cache, toks0), pool=wpool)[0]
             if self._decode_exe.host_mode == "static":
                 self._run_exe(self._decode_exe, (params, warm_cache, toks0),
                               pool=wpool, host_mode="dynamic")
-            _, kc, vc = self._run_exe(
+            chunk_logits, kc, vc = self._run_exe(
                 self._chunk_exe,
                 (params, warm_pages, jnp.full((self.n_pt,), -1, jnp.int32),
                  {"tokens": jnp.zeros((1, self.chunk), jnp.int32)},
                  jnp.int32(0), jnp.int32(self.chunk)),
                 pool=wpool)
-        sample_tokens(logits, cfg.vocab_size, scfg.temperature,
-                      jax.random.key(0) if scfg.temperature > 0 else None)
+        # the sampler runs on decode logits and on a finished prefill's
+        for lg in (logits, chunk_logits):
+            sample_tokens(lg, cfg.vocab_size, scfg.temperature,
+                          jax.random.key(0) if scfg.temperature > 0 else None)
         warm_pages = self._insert_chunk(
             warm_pages, jnp.full((self.n_pt,), -1, jnp.int32),
             jnp.int32(0), jnp.int32(self.chunk), kc, vc)
         warm_pages = self._copy_page(warm_pages, jnp.int32(0), jnp.int32(0))
         jax.block_until_ready(jax.tree.leaves(warm_pages)[0])
+        self._compiles_at_ready = self._process_trace.n_compiles
 
     # -- lifecycle -------------------------------------------------------------
     def close(self) -> None:
@@ -440,6 +466,11 @@ class PagedEngine(_SamplerMixin):
             "n_cold_reclaims": self.page_pool.n_cold_reclaims,
             "peak_pages": self.page_pool.peak_used,
             "peak_kv_bytes": int(self.page_pool.peak_used * self.page_bytes),
+            # compilations (and compile-cache loads), on any thread of the
+            # process, since this engine finished warming up: none in
+            # steady serving
+            "n_compiles": (self._process_trace.n_compiles
+                           - self._compiles_at_ready),
         }
 
     # -- executor plumbing (same shape as ContinuousEngine) --------------------
@@ -525,8 +556,9 @@ class PagedEngine(_SamplerMixin):
             if partial is not None:
                 src, n_common = partial
                 dst = self._alloc_page(protect={slot})
-                self._pages = self._copy_page(
-                    self._pages, jnp.int32(src), jnp.int32(dst))
+                with span(_COW_COPY):
+                    self._pages = self._copy_page(
+                        self._pages, jnp.int32(src), jnp.int32(dst))
                 self._table[slot, len(full)] = dst
                 task.pos += n_common
                 self.n_cow_copies += 1
@@ -540,20 +572,31 @@ class PagedEngine(_SamplerMixin):
                 self._table[slot, j] = self._alloc_page(protect={slot})
 
     def _finish_prefill(self, slot: int, task: _PrefillTask, logits) -> None:
-        del self.prefills[slot]
-        self._len[slot] = task.total
-        ps = self.pcfg.page_size
-        if self.pcfg.share_prefix:
-            for j in range(-(-task.total // ps)):
-                pid = int(self._table[slot, j])
-                if pid >= 0:
-                    self.page_pool.register(
-                        pid, task.tokens, j * ps,
-                        min(ps, task.total - j * ps))
-        self.slots[slot] = task.req
-        self._emit(slot, int(self._sample(logits)[0]))
+        # the host registers the pages while the device still works; only
+        # then does it wait for the first token
+        with span(_EMIT):
+            del self.prefills[slot]
+            self._len[slot] = task.total
+            ps = self.pcfg.page_size
+            if self.pcfg.share_prefix:
+                for j in range(-(-task.total // ps)):
+                    pid = int(self._table[slot, j])
+                    if pid >= 0:
+                        self.page_pool.register(
+                            pid, task.tokens, j * ps,
+                            min(ps, task.total - j * ps))
+            self.slots[slot] = task.req
+        token = int(self._sample(logits)[0])
+        with span(_EMIT):
+            self._emit(slot, token)
 
     # -- decode / emit ---------------------------------------------------------
+    def _sample(self, logits) -> np.ndarray:
+        with span(_SAMPLE):
+            toks = self._sample_on_device(logits)
+        with span(_READBACK):
+            return np.asarray(toks, np.int32)
+
     def _emit(self, slot: int, token: int) -> None:
         req = self.slots[slot]
         req.output.append(token)
@@ -571,67 +614,78 @@ class PagedEngine(_SamplerMixin):
         # idle rows (free, or mid-prefill) decode against an empty table:
         # their pool writes redirect out of bounds and drop, their logits
         # are discarded
-        tbl = self._table.copy()
-        ln = self._len.copy()
-        for i in range(self.capacity):
-            if self.slots[i] is None:
-                tbl[i] = -1
-                ln[i] = 0
-        cache = {"len": jnp.asarray(ln), "table": jnp.asarray(tbl),
-                 "pages": self._pages}
+        with span(_UPLOAD):
+            tbl = self._table.copy()
+            ln = self._len.copy()
+            for i in range(self.capacity):
+                if self.slots[i] is None:
+                    tbl[i] = -1
+                    ln[i] = 0
+            cache = {"len": jnp.asarray(ln), "table": jnp.asarray(tbl),
+                     "pages": self._pages}
+            toks = jnp.asarray(self._tokens)
         host_mode = None
         if overlapping and self._decode_exe.host_mode == "static":
             # same reasoning as ContinuousEngine: a static plan's segments
             # would serialize the concurrent chunk prefills behind the
             # decode, so overlapped steps fall back to the dynamic scheduler
             host_mode = "dynamic"
-        logits, out = self._run_exe(
-            self._decode_exe, (self.params, cache, jnp.asarray(self._tokens)),
-            pool=pool, host_mode=host_mode)
+        with span(_DECODE):
+            logits, out = self._run_exe(
+                self._decode_exe, (self.params, cache, toks),
+                pool=pool, host_mode=host_mode)
         self._pages = out["pages"]
         self.n_decode_steps += 1
         nxt = self._sample(logits)
-        for i in range(self.capacity):
-            if self.slots[i] is not None:
-                self._len[i] += 1
-                self._emit(i, int(nxt[i]))
+        with span(_EMIT):
+            for i in range(self.capacity):
+                if self.slots[i] is not None:
+                    self._len[i] += 1
+                    self._emit(i, int(nxt[i]))
 
     def _run_chunk(self, pages_in, slot: int, start: int, valid: int,
                    toks: np.ndarray, pool):
-        return self._run_exe(
-            self._chunk_exe,
-            (self.params, pages_in, jnp.asarray(self._table[slot]),
-             {"tokens": jnp.asarray(toks)},
-             jnp.int32(start), jnp.int32(valid)),
-            pool=pool)
+        with span(_CHUNK):
+            return self._run_exe(
+                self._chunk_exe,
+                (self.params, pages_in, jnp.asarray(self._table[slot]),
+                 {"tokens": jnp.asarray(toks)},
+                 jnp.int32(start), jnp.int32(valid)),
+                pool=pool)
 
     # -- the loop --------------------------------------------------------------
     def step(self) -> bool:
         """One engine iteration: admit, allocate pages, run one decode step
         concurrently with one prefill chunk per in-flight prompt, install
         chunk K/V, retire finished requests.  Returns whether work remains."""
+        with span(_STEP):
+            return self._step()
+
+    def _step(self) -> bool:
         self.n_steps += 1
         if self.step_deadline_s is not None:
             self._step_deadline = time.monotonic() + self.step_deadline_s
         ps = self.pcfg.page_size
 
         # 1. admit pending requests into free slots (prefix share / CoW)
-        free = [i for i in range(self.capacity)
-                if self.slots[i] is None and i not in self.prefills]
-        while self.pending and free:
-            self._begin_prefill(self.pending.popleft(), free.pop(0))
+        with span(_ADMIT):
+            free = [i for i in range(self.capacity)
+                    if self.slots[i] is None and i not in self.prefills]
+            while self.pending and free:
+                self._begin_prefill(self.pending.popleft(), free.pop(0))
 
         # 2. allocate this step's pages: chunk spans, then decode boundary
         # pages.  Allocation may evict requests (youngest first), so re-check
         # liveness at each use.
-        for slot, task in list(self.prefills.items()):
-            if slot in self.prefills:
-                self._alloc_chunk_pages(slot, task)
-        for i in range(self.capacity):
-            if (self.slots[i] is not None and self._len[i] % ps == 0
-                    and self._table[i, self._len[i] // ps] < 0):
-                self._table[i, self._len[i] // ps] = self._alloc_page(
-                    protect={i})
+        with span(_ALLOC):
+            for slot, task in list(self.prefills.items()):
+                if slot in self.prefills:
+                    self._alloc_chunk_pages(slot, task)
+            for i in range(self.capacity):
+                if (self.slots[i] is not None and self._len[i] % ps == 0
+                        and self._table[i, self._len[i] // ps] < 0):
+                    self._table[i, self._len[i] // ps] = self._alloc_page(
+                        protect={i})
 
         # 3. run: one chunk per surviving prefill, overlapped with decode
         jobs = []
@@ -646,11 +700,14 @@ class PagedEngine(_SamplerMixin):
         # can never alias what a chunk reads
         pages_in = self._pages
         results = None
-        with self._step_pool() as pool:
+        with span(_LEASE):
+            lease = self._step_pool()
+        with lease as pool:
             if jobs and decoding:
                 box: dict = {}
 
                 def chunk_worker() -> None:
+                    trace.name_thread(_PREFILL_THREAD)
                     try:
                         box["res"] = [
                             self._run_chunk(pages_in, s, p, t, tk, pool)
@@ -662,7 +719,8 @@ class PagedEngine(_SamplerMixin):
                                       name="serve-paged-prefill")
                 th.start()
                 self._decode_once(pool, overlapping=True)
-                th.join()
+                with span(_CHUNK_JOIN):
+                    th.join()
                 if "err" in box:
                     raise box["err"]
                 self.n_overlapped_chunks += len(jobs)
@@ -677,9 +735,10 @@ class PagedEngine(_SamplerMixin):
         # activate finished prefills
         if results:
             for (slot, task, start, T, _), (logits, kc, vc) in zip(jobs, results):
-                self._pages = self._insert_chunk(
-                    self._pages, jnp.asarray(self._table[slot]),
-                    jnp.int32(start), jnp.int32(T), kc, vc)
+                with span(_INSERT_CHUNK):
+                    self._pages = self._insert_chunk(
+                        self._pages, jnp.asarray(self._table[slot]),
+                        jnp.int32(start), jnp.int32(T), kc, vc)
                 self.n_chunks += 1
                 task.pos = start + T
                 if task.pos >= task.total:
