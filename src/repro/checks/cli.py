@@ -126,7 +126,7 @@ def run_zoo_arch(arch: str) -> Report:
                           "pages": pages}
             tok = jnp.zeros((_B, 1), jnp.int32)
             dec = repro.compile(
-                make_paged_decode_step(cfg, _PAGE), params, cache_spec, tok,
+                make_paged_decode_step(cfg), params, cache_spec, tok,
                 n_workers=_N_WORKERS, name=f"{arch}.paged_decode")
             row = jnp.full((n_pt,), -1, jnp.int32)
             chunk_batch = {"tokens": jnp.zeros((1, _PAGE), jnp.int32)}
@@ -138,9 +138,9 @@ def run_zoo_arch(arch: str) -> Report:
             sub.extend(_check_executable(dec, f"{arch}/paged_decode"))
             sub.extend(_check_executable(chunk, f"{arch}/prefill_chunk"))
 
-            # cross-graph: the decode step scatters into the pools; every
-            # chunk-prefill must be read-only over them (PR 6's concurrency
-            # protocol) — certified here, not assumed
+            # cross-graph: both graphs read the one pool, concurrently, and
+            # neither may write it — the engine writes it only after both
+            # are dispatched.  Certified here, not assumed
             eff_d = infer_effects(dec.graph)
             eff_c = infer_effects(chunk.graph)
             bind_d = dec.captured.bind((params, cache_spec, tok))
@@ -155,17 +155,21 @@ def run_zoo_arch(arch: str) -> Report:
                         "paged decode and prefill chunk share no pool "
                         "buffers — alias discovery broke",
                         where=f"{arch}/paged")
-            if not eff_d.written() & {a for a, _ in pool_shared}:
-                sub.add("H-XWW", "error",
-                        "paged decode writes no pool buffer — effect "
-                        "inference lost the scan-body scatters",
-                        where=f"{arch}/paged")
             sub.extend(cross_graph_hazards(eff_d, eff_c, shared))
-            if eff_c.read_only(b for _, b in pool_shared):
-                sub.add("C-RO", "info",
-                        f"prefill chunk certified read-only over "
-                        f"{len(pool_shared)} shared pool buffer(s)",
-                        where=f"{arch}/paged")
+            for label, eff, bufs in (
+                    ("paged decode", eff_d, [a for a, _ in pool_shared]),
+                    ("prefill chunk", eff_c, [b for _, b in pool_shared])):
+                if eff.read_only(bufs):
+                    sub.add("C-RO", "info",
+                            f"{label} certified read-only over "
+                            f"{len(bufs)} shared pool buffer(s)",
+                            where=f"{arch}/paged")
+                else:
+                    sub.add("H-XWW", "error",
+                            f"{label} writes a pool buffer — the pool "
+                            "must change only through the engine's "
+                            "donated installs",
+                            where=f"{arch}/paged")
             return sub
 
         guarded("paged", paged)

@@ -19,8 +19,8 @@ of:
   unchanged;
 * ``scan`` / ``while`` / ``cond`` and call-like primitives recurse into
   their sub-jaxprs with positional argument mapping, iterating loop carries
-  to a fixpoint — the paged decode's pool scatters live *inside* a
-  ``lax.scan`` over layers and must still be seen;
+  to a fixpoint — a scatter *inside* a ``lax.scan`` over layers must
+  still be seen;
 * every other primitive reads its operands and produces fresh values.
 
 Hand-built graphs (no jaxpr meta) may annotate nodes explicitly with

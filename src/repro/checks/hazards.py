@@ -20,9 +20,9 @@ pairs (:func:`~repro.checks.effects.shared_buffers`) and reports:
 * **H-XWW** (error) — both graphs write a shared buffer: never safe to run
   concurrently;
 * **H-XRW** (info)  — one writes, the other only reads: safe exactly when
-  the caller serializes the runs externally (the paged serving engine's
-  insert-after-decode protocol), which is why chunked-prefill graphs must
-  stay read-only over the pools — the certification this rule states.
+  the caller serializes the runs externally.  The paged serving engine
+  needs neither: its decode and chunked-prefill graphs are both read-only
+  over the pool, and its writes come after both are dispatched.
 """
 from __future__ import annotations
 

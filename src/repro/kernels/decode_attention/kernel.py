@@ -125,32 +125,41 @@ def decode_attention_kernel_call(
 
 
 # ---------------------------------------------------------------------------
-# paged variant: the KV lives in a global page pool [P, ps, Hkv, hd] and each
-# batch row owns a page *table*.  The table is a scalar-prefetch operand
-# (PrefetchScalarGridSpec), so the BlockSpec index map itself chases the
-# table: grid step (b, ip) DMAs physical page table[b, ip] — the kernel
-# never materializes a gathered [B, S] cache, it streams exactly the pages
-# the row owns.  Unmapped entries (table[b, ip] < 0) clamp to page 0 for the
-# DMA and are masked out of the online softmax in the body.
+# paged variant: the KV lives in a global page pool [P, L, ps, Hkv, hd] (a
+# physical page holds its tokens for every layer) and each batch row owns a
+# page *table*.  The layer and the table are scalar-prefetch operands
+# (PrefetchScalarGridSpec), so the BlockSpec index map itself chases them:
+# grid step (b, ip) DMAs layer `layer` of page table[b, ip] straight out of
+# the pool — the kernel never materializes a gathered [B, S] cache, nor a
+# slice of one layer's pool.  Unmapped entries (table[b, ip] < 0) clamp to page 0 for the DMA and
+# are masked out of the online softmax in the body.
+#
+# The pool holds the positions below each row's q_pos; the row's own token
+# (its K/V computed this step, not yet in the pool) seeds the online softmax,
+# so the decode step reads the pool and never writes it.
 # ---------------------------------------------------------------------------
 
 def _paged_kernel(
-    table_ref, qpos_ref,                 # scalar-prefetch: [B, n_pt], [B]
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+    layer_ref, table_ref, qpos_ref,      # scalar-prefetch: [1], [B, n_pt], [B]
+    q_ref, knew_ref, vnew_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     *, n_pt: int, ps: int, G: int, window: int | None, scale: float,
 ):
+    del layer_ref                        # consumed by the index maps
     b = pl.program_id(0)
     ip = pl.program_id(1)
+    q = q_ref[0].astype(jnp.float32) * scale     # [Hq, hd]
 
     @pl.when(ip == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        # the fresh token at q_pos: k_new/v_new come repeated per query head
+        s_new = jnp.sum(q * knew_ref[0].astype(jnp.float32), axis=-1,
+                        keepdims=True)           # [Hq, 1]
+        acc_ref[...] = vnew_ref[0].astype(jnp.float32)
+        m_ref[...] = jnp.broadcast_to(s_new, m_ref.shape)
+        l_ref[...] = jnp.ones_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale     # [Hq, hd]
-    k = k_ref[0]                                 # [ps, Hkv, hd]
-    v = v_ref[0]
+    k = k_ref[0, 0]                              # [ps, Hkv, hd]
+    v = v_ref[0, 0]
     page = table_ref[b, ip]
     q_pos = qpos_ref[b]
     kv_pos = ip * ps + jax.lax.broadcasted_iota(jnp.int32, (ps,), 0)
@@ -163,7 +172,7 @@ def _paged_kernel(
         (((2,), (2,)), ((0,), (1,))),
         preferred_element_type=jnp.float32,
     )                                            # [Hkv, G, ps]
-    keep = (page >= 0) & (kv_pos <= q_pos)
+    keep = (page >= 0) & (kv_pos < q_pos)
     if window is not None:
         keep &= kv_pos > q_pos - window
     s = jnp.where(keep[None, None, :], s, _NEG_INF)
@@ -186,14 +195,16 @@ def _paged_kernel(
 
     @pl.when(ip == n_pt - 1)
     def _done():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
 def paged_decode_attention_kernel_call(
     q: jax.Array,           # [B, Hq, hd]
-    k_pages: jax.Array,     # [P, ps, Hkv, hd]
+    k_new: jax.Array,       # [B, Hkv, hd] the row's own K/V at q_pos
+    v_new: jax.Array,
+    k_pages: jax.Array,     # [P, L, ps, Hkv, W], W >= hd (lane-padded)
     v_pages: jax.Array,
+    layer: jax.Array,       # [] int32
     page_table: jax.Array,  # [B, n_pt] int32, -1 = unmapped
     q_pos: jax.Array,       # [B] int32
     *,
@@ -201,39 +212,44 @@ def paged_decode_attention_kernel_call(
     interpret: bool,
 ) -> jax.Array:
     B, Hq, hd = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    _, _, ps, Hkv, W = k_pages.shape
     n_pt = page_table.shape[1]
     if Hq % Hkv != 0:
         raise ValueError(f"Hq={Hq} not a multiple of Hkv={Hkv}")
     G = Hq // Hkv
+    # the row's operands take the pool's lane width (zeros past hd add
+    # nothing to q.k or to the output, which drops them); the fresh K/V
+    # come once per query head, so the seed is a plain product with q (a
+    # few KB a row, against a page walk of megabytes)
+    lanes = [(0, 0), (0, 0), (0, W - hd)]
+    q = jnp.pad(q, lanes)
+    k_new = jnp.pad(jnp.repeat(k_new, G, axis=1), lanes)
+    v_new = jnp.pad(jnp.repeat(v_new, G, axis=1), lanes)
 
     kern = functools.partial(
         _paged_kernel, n_pt=n_pt, ps=ps, G=G, window=window, scale=hd ** -0.5,
     )
+    row = pl.BlockSpec((1, Hq, W), lambda b, ip, lyr, tbl, qp: (b, 0, 0))
+    page = pl.BlockSpec(
+        (1, 1, ps, Hkv, W),
+        lambda b, ip, lyr, tbl, qp: (jnp.maximum(tbl[b, ip], 0), lyr[0], 0, 0, 0),
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, n_pt),
-        in_specs=[
-            pl.BlockSpec((1, Hq, hd), lambda b, ip, tbl, qp: (b, 0, 0)),
-            pl.BlockSpec(
-                (1, ps, Hkv, hd),
-                lambda b, ip, tbl, qp: (jnp.maximum(tbl[b, ip], 0), 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, ps, Hkv, hd),
-                lambda b, ip, tbl, qp: (jnp.maximum(tbl[b, ip], 0), 0, 0, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, hd), lambda b, ip, tbl, qp: (b, 0, 0)),
+        in_specs=[row, row, row, page, page],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((Hq, hd), jnp.float32),
+            pltpu.VMEM((Hq, W), jnp.float32),
             pltpu.VMEM((Hq, 128), jnp.float32),
             pltpu.VMEM((Hq, 128), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, W), q.dtype),
         interpret=interpret,
-    )(page_table, q_pos, q, k_pages, v_pages)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), page_table, q_pos,
+      q, k_new, v_new, k_pages, v_pages)
+    return out[..., :hd]

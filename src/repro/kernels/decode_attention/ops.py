@@ -41,8 +41,12 @@ def decode_attention(
 
 def paged_decode_attention(
     q: jax.Array,           # [B, 1, Hq, hd] (model layout) or [B, Hq, hd]
-    k_pages: jax.Array,     # [P, ps, Hkv, hd] global page pool
+    k_new: jax.Array,       # [B, Hkv, hd] each row's own K/V at q_pos
+    v_new: jax.Array,
+    k_pages: jax.Array,     # [P, L, ps, Hkv, W] every layer's page pool,
+                            # W >= hd (lane-padded; entries past hd unused)
     v_pages: jax.Array,
+    layer: jax.Array,       # [] the layer whose pages are read
     page_table: jax.Array,  # [B, n_pt] physical page ids, -1 = unmapped
     q_pos: jax.Array,       # [B] absolute position per row
     *,
@@ -54,15 +58,21 @@ def paged_decode_attention(
 
     Logical position of page-table entry ``(j, t)`` is ``j*ps + t``, so a
     request's pages reconstruct its linear KV cache without the cache ever
-    existing contiguously.  Two paths:
+    existing contiguously.  The pool is read in place, by (page, layer), and
+    holds the positions strictly below ``q_pos``; the row's own token
+    (``k_new``/``v_new``, not yet written) is attended to as position
+    ``q_pos``, so the caller writes it into the pool after the step.  Two
+    paths:
 
     - the pure-jnp gather path (default off-TPU): an explicit
-      ``pages[table]`` gather plus the same position-table-masked softmax
-      as :func:`decode_attention`, so graphi fuses the gather into the
+      ``pages[table, layer]`` gather with the fresh token put in at
+      ``q_pos``, then the same position-table-masked softmax as
+      :func:`decode_attention`, so graphi fuses the gather into the
       attention group and ``StaticHostPlan`` replay sees a fixed-shape
       movement op;
     - the Pallas kernel (``REPRO_USE_PALLAS=1`` or real TPU), whose
-      scalar-prefetch BlockSpec index map chases the page table directly.
+      scalar-prefetch BlockSpec index map chases the layer and the page
+      table directly, and whose online softmax starts from the fresh token.
 
     The path is resolved here, outside the jit, so it is part of the jit's
     cache key: flipping ``REPRO_USE_PALLAS`` never reuses the other path's
@@ -74,15 +84,15 @@ def paged_decode_attention(
         use_kernel = kernels_enabled()
     if interpret is None:
         interpret = default_interpret()
-    return _paged_decode_attention(q, k_pages, v_pages, page_table, q_pos,
-                                   window=window, use_kernel=use_kernel,
-                                   interpret=interpret)
+    return _paged_decode_attention(q, k_new, v_new, k_pages, v_pages, layer,
+                                   page_table, q_pos, window=window,
+                                   use_kernel=use_kernel, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("window", "use_kernel", "interpret"))
-def _paged_decode_attention(q, k_pages, v_pages, page_table, q_pos, *,
-                            window: int | None, use_kernel: bool,
-                            interpret: bool) -> jax.Array:
+def _paged_decode_attention(q, k_new, v_new, k_pages, v_pages, layer,
+                            page_table, q_pos, *, window: int | None,
+                            use_kernel: bool, interpret: bool) -> jax.Array:
     from .kernel import paged_decode_attention_kernel_call
 
     squeeze = q.ndim == 4
@@ -90,21 +100,25 @@ def _paged_decode_attention(q, k_pages, v_pages, page_table, q_pos, *,
         q = q[:, 0]
     page_table = page_table.astype(jnp.int32)
     q_pos = q_pos.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
     if use_kernel:
         out = paged_decode_attention_kernel_call(
-            q, k_pages, v_pages, page_table, q_pos,
+            q, k_new, v_new, k_pages, v_pages, layer, page_table, q_pos,
             window=window, interpret=interpret,
         )
         return out[:, None] if squeeze else out
 
     B, Hq, hd = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    ps, Hkv = k_pages.shape[2:4]
     n_pt = page_table.shape[1]
     clamped = jnp.maximum(page_table, 0)
-    kc = k_pages[clamped].reshape(B, n_pt * ps, Hkv, hd)
-    vc = v_pages[clamped].reshape(B, n_pt * ps, Hkv, hd)
     idx = jnp.arange(n_pt * ps)
-    mapped = jnp.repeat(page_table >= 0, ps, axis=1)
+    fresh = (idx[None] == q_pos[:, None])[..., None, None]
+    kc = jnp.where(fresh, k_new[:, None],
+                   k_pages[clamped, layer, ..., :hd].reshape(B, n_pt * ps, Hkv, hd))
+    vc = jnp.where(fresh, v_new[:, None],
+                   v_pages[clamped, layer, ..., :hd].reshape(B, n_pt * ps, Hkv, hd))
+    mapped = jnp.repeat(page_table >= 0, ps, axis=1) | fresh[..., 0, 0]
     kv_pos = jnp.where(mapped, idx[None], -1)
     # masked softmax identical (op for op) to layers.decode_attention's 2-D
     # path: the paged engine must stay bit-exact with the per-slot engine

@@ -312,9 +312,10 @@ def main() -> int:
         for exe in exes:
             rep.extend(exe.verify(hazards=True))
         if chunk_exe is not None:
-            # the decode step scatters into the page pools the chunk graph
-            # reads — both bind the engine's one ``_pages`` object, so alias
-            # discovery is by array identity over the two bound input maps
+            # the decode step and the chunk graph read the engine's one
+            # ``_pages`` pool and must not write it — both bind that object,
+            # so alias discovery is by array identity over the two bound
+            # input maps
             cache_spec = {
                 "len": jnp.zeros((engine.capacity,), jnp.int32),
                 "table": jnp.full((engine.capacity, engine.n_pt), -1,

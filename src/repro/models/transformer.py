@@ -55,6 +55,7 @@ __all__ = [
     "paged_decode_step",
     "paged_prefill_chunk",
     "paged_insert_chunk",
+    "paged_install_decode",
     "paged_copy_page",
 ]
 
@@ -681,18 +682,31 @@ def decode_step(cfg: ModelConfig, params, tokens, cache: dict):
 # paged KV cache: global page pool + per-request page tables
 # ---------------------------------------------------------------------------
 #
-# Layout: per layer a page pool {"k": [P, ps, Hkv, hd], "v": ...} (leading
-# layer axis when the arch scans stacked layers), a page table [B, n_pt]
-# mapping each slot's logical page j to a physical page id (-1 = unmapped),
-# and per-slot lengths [B].  The logical KV position of table entry (j, t)
-# is j*ps + t, so a request's pages reconstruct its linear cache without it
-# ever existing contiguously — one short request pins ceil(len/ps) pages
-# instead of a full max_len slot, and requests sharing a prompt prefix can
-# map the *same* physical pages (serve/paged.py owns refcounts + CoW).
+# Layout: one page pool {"k": [P, L, ps, Hkv, W], "v": ...} — a physical
+# page holds its ps tokens for every layer, whether or not the arch scans
+# its layers — a page table [B, n_pt] mapping each slot's logical page j to
+# a physical page id (-1 = unmapped), and per-slot lengths [B].  The logical
+# KV position of table entry (j, t) is j*ps + t, so a request's pages
+# reconstruct its linear cache without it ever existing contiguously — one
+# short request pins ceil(len/ps) pages instead of a full max_len slot, and
+# requests sharing a prompt prefix can map the *same* physical pages
+# (serve/paged.py owns refcounts + CoW).
+#
+# W is the head size rounded up to the TPU's 128 lanes (zeros past it): the
+# TPU's default layout of the pool is then row-major, which the paged
+# decode kernel reads as it is.  With a head size off the lanes (danube3's
+# 120), or with the layer axis leading, the default layout puts the page
+# axis minor-most and every kernel call would first copy the whole pool into
+# row-major order; row-major storage pads a 120-wide row to 128 lanes
+# anyway, so W costs no memory over it.
 #
 # The page table and lengths are host-managed (numpy in the serving engine,
-# passed in as int32 arrays per step); only the pools are threaded through
-# the captured decode graph functionally.
+# passed in as int32 arrays per step).  The decode and chunk graphs only
+# read the pool, in place by (page, layer), and return the K/V they computed;
+# the engine writes those with paged_install_decode / paged_insert_chunk,
+# which update the pool it donates — no program moves the pool whole.
+_LANES = 128
+
 
 def paged_supported(cfg: ModelConfig) -> bool:
     """Paged serving covers decoder-only, attention-only, rope archs: SSM /
@@ -711,28 +725,19 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int, *,
                      n_pages: int, page_size: int) -> dict:
     """Paged KV cache for ``batch`` request slots over a ``n_pages``-page
     global pool.  ``table``/``len`` come back as numpy (host-managed by the
-    allocator); ``pages`` are device arrays threaded through decode."""
+    allocator); ``pages`` are the device pool of every layer."""
     if not paged_supported(cfg):
         raise ValueError("paged KV cache requires a decoder-only "
                          "attention-only rope arch "
                          f"(got kinds={cfg.layer_kinds()}, frontend={cfg.frontend!r})")
     n_pt = -(-max_len // page_size)
-    hd = cfg.resolved_head_dim
-    dtype = cfg.dtype
-
-    def pool():
-        return {"k": jnp.zeros((n_pages, page_size, cfg.n_kv_heads, hd), dtype),
-                "v": jnp.zeros((n_pages, page_size, cfg.n_kv_heads, hd), dtype)}
-
-    if _paged_stacked(cfg):
-        per = [pool() for _ in range(cfg.n_layers)]
-        pages = jax.tree.map(lambda *xs: jnp.stack(xs), *per)
-    else:
-        pages = [pool() for _ in range(cfg.n_layers)]
+    lanes = -(-cfg.resolved_head_dim // _LANES) * _LANES
+    shape = (n_pages, cfg.n_layers, page_size, cfg.n_kv_heads, lanes)
     return {
         "len": np.zeros((batch,), np.int32),
         "table": np.full((batch, n_pt), -1, np.int32),
-        "pages": pages,
+        "pages": {"k": jnp.zeros(shape, cfg.dtype),
+                  "v": jnp.zeros(shape, cfg.dtype)},
     }
 
 
@@ -759,71 +764,67 @@ def free_pages(cache: dict, slot: int) -> tuple[dict, list[int]]:
     return {**cache, "table": table, "len": length}, freed
 
 
-def _paged_block_decode(cfg: ModelConfig, lp, x, pk, pv, table, q_pos, *,
-                        page_size: int):
-    """Single-token block step over the page pool.  x: [B,1,D];
-    pk/pv: [P, ps, Hkv, hd]; table: [B, n_pt]; q_pos: [B]."""
-    from repro.kernels.decode_attention import paged_decode_attention
-
-    ap = lp["attn"]
-    B = x.shape[0]
-    hd = cfg.resolved_head_dim
-    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-    q = jnp.einsum("bsd,de->bse", h, ap["wq"]).reshape(B, 1, cfg.n_heads, hd)
-    k = jnp.einsum("bsd,de->bse", h, ap["wk"]).reshape(B, 1, cfg.n_kv_heads, hd)
-    v = jnp.einsum("bsd,de->bse", h, ap["wv"]).reshape(B, 1, cfg.n_kv_heads, hd)
-    pos_arr = q_pos[:, None]
-    q = apply_rope(q, pos_arr, cfg.rope_theta)
-    k = apply_rope(k, pos_arr, cfg.rope_theta)
-    P, ps = pk.shape[0], page_size
-    # write this token's K/V at (table[b, len//ps], len%ps); rows whose tail
-    # page is unmapped (idle slots) redirect to the out-of-bounds page P and
-    # the scatter drops them — never a wrapped write into page P-1
-    phys = jnp.take_along_axis(table, (q_pos // ps)[:, None], axis=1)[:, 0]
-    phys = jnp.where(phys < 0, P, phys)
-    off = q_pos % ps
-    pk = pk.at[phys, off].set(k[:, 0], mode="drop")
-    pv = pv.at[phys, off].set(v[:, 0], mode="drop")
-    out = paged_decode_attention(q, pk, pv, table, q_pos,
-                                 window=_window_for(cfg, "attn"))
-    mix = jnp.einsum("bse,ed->bsd", out.reshape(B, 1, -1), ap["wo"])
-    if cfg.parallel_block:
-        mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h)
-        return x + mix + mlp_out, pk, pv
-    x = x + mix
-    h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h2)
-    return x + mlp_out, pk, pv
-
-
-def paged_decode_step(cfg: ModelConfig, params, tokens, cache: dict, *,
-                      page_size: int):
-    """One decode step over the paged cache.  tokens: [B, 1].
-    Returns (logits [B, Vp], new cache with updated pools and len+1)."""
-    q_pos = jnp.asarray(cache["len"], jnp.int32)
-    table = jnp.asarray(cache["table"], jnp.int32)
-    x = _embed(cfg, params, tokens, pos_offset=q_pos)
-
+def _paged_layers(cfg: ModelConfig, params, x, block):
+    """Run ``block(x, lp, layer) -> (x, (k, v))`` over every layer, scanned
+    when the arch stacks its layers; returns x and the stacked [L, ...] K/V.
+    The pool is closed over, never a scan operand: each layer reads its
+    pages in place through ``layer``."""
     if _paged_stacked(cfg):
         def body(x, inp):
-            lp, pg = inp
-            x, pk, pv = _paged_block_decode(cfg, lp, x, pg["k"], pg["v"],
-                                            table, q_pos, page_size=page_size)
-            return x, {"k": pk, "v": pv}
+            lp, layer = inp
+            return block(x, lp, layer)
 
-        x, new_pages = jax.lax.scan(body, x, (params["layers"], cache["pages"]))
-    else:
-        new_pages = []
-        for lp, pg in zip(params["layers"], cache["pages"]):
-            x, pk, pv = _paged_block_decode(cfg, lp, x, pg["k"], pg["v"],
-                                            table, q_pos, page_size=page_size)
-            new_pages.append({"k": pk, "v": pv})
+        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        return jax.lax.scan(body, x, (params["layers"], layers))
+    ks, vs = [], []
+    for layer, lp in enumerate(params["layers"]):
+        x, (k, v) = block(x, lp, jnp.int32(layer))
+        ks.append(k)
+        vs.append(v)
+    return x, (jnp.stack(ks), jnp.stack(vs))
 
-    cache = dict(cache)
-    cache["pages"] = new_pages
-    cache["len"] = q_pos + 1
+
+def paged_decode_step(cfg: ModelConfig, params, tokens, cache: dict):
+    """One decode step over the paged cache.  tokens: [B, 1].
+
+    Reads the pool (positions below each row's length) and writes nothing:
+    returns (logits [B, Vp], {"k", "v"}: this step's K/V [L, B, Hkv, hd]),
+    which the engine installs at each row's length with
+    :func:`paged_install_decode`."""
+    from repro.kernels.decode_attention import paged_decode_attention
+
+    q_pos = jnp.asarray(cache["len"], jnp.int32)
+    table = jnp.asarray(cache["table"], jnp.int32)
+    pages = cache["pages"]
+    x = _embed(cfg, params, tokens, pos_offset=q_pos)
+    B = x.shape[0]
+    hd = cfg.resolved_head_dim
+    pos_arr = q_pos[:, None]
+
+    def block(x, lp, layer):
+        ap = lp["attn"]
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        q = jnp.einsum("bsd,de->bse", h, ap["wq"]).reshape(B, 1, cfg.n_heads, hd)
+        k = jnp.einsum("bsd,de->bse", h, ap["wk"]).reshape(B, 1, cfg.n_kv_heads, hd)
+        v = jnp.einsum("bsd,de->bse", h, ap["wv"]).reshape(B, 1, cfg.n_kv_heads, hd)
+        q = apply_rope(q, pos_arr, cfg.rope_theta)
+        k = apply_rope(k, pos_arr, cfg.rope_theta)[:, 0]
+        v = v[:, 0]
+        out = paged_decode_attention(q, k, v, pages["k"], pages["v"], layer,
+                                     table, q_pos,
+                                     window=_window_for(cfg, "attn"))
+        mix = jnp.einsum("bse,ed->bsd", out.reshape(B, 1, -1), ap["wo"])
+        if cfg.parallel_block:
+            mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h)
+            return x + mix + mlp_out, (k, v)
+        x = x + mix
+        h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
+        mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h2)
+        return x + mlp_out, (k, v)
+
+    x, (k_new, v_new) = _paged_layers(cfg, params, x, block)
     x = rms_norm(x[:, -1], params["final_norm"], cfg.norm_eps)
-    return _logits(cfg, params, x), cache
+    return _logits(cfg, params, x), {"k": k_new, "v": v_new}
 
 
 def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
@@ -832,15 +833,15 @@ def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
 
     tokens: [1, T] (right-padded; first ``valid_len`` real), table_row:
     [n_pt] — the request's page-table row, ``start`` — the absolute position
-    of tokens[0].  Reads already-computed context K/V from the pools
+    of tokens[0].  Reads already-computed context K/V from the pool
     (entries at positions < start; the mask is *strict* so stale data in the
     partially-filled tail page never leaks in), computes the chunk's K/V and
-    returns it **without writing**: the engine scatters it into the pools
+    returns it **without writing**: the engine scatters it into the pool
     afterwards (paged_insert_chunk), which keeps this graph free of pool
-    writes and lets it run concurrently with the decode step's.
+    writes and lets it run concurrently with the decode step.
 
     Returns (logits [1, Vp] at position start+valid_len-1,
-    k_chunk, v_chunk — [L, T, Hkv, hd] stacked or per-layer lists).
+    k_chunk, v_chunk — [L, T, Hkv, hd]).
     """
     T = tokens.shape[1]
     start = jnp.asarray(start, jnp.int32)
@@ -850,8 +851,15 @@ def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
     x = _embed(cfg, params, tokens)                        # rope: positionless
     hd = cfg.resolved_head_dim
     window = _window_for(cfg, "attn")
+    n_pt = table_row.shape[0]
+    ps = page_size
+    rows = jnp.maximum(table_row, 0)
+    idx = jnp.arange(n_pt * ps, dtype=jnp.int32)
+    mapped = jnp.repeat(table_row >= 0, ps)
+    ctx_pos = jnp.where(mapped & (idx < start), idx, -1)
+    kv_pos = jnp.concatenate([ctx_pos, pos])
 
-    def run_block(x, lp, pg):
+    def block(x, lp, layer):
         ap = lp["attn"]
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         q = jnp.einsum("bsd,de->bse", h, ap["wq"]).reshape(1, T, cfg.n_heads, hd)
@@ -859,17 +867,13 @@ def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
         v = jnp.einsum("bsd,de->bse", h, ap["wv"]).reshape(1, T, cfg.n_kv_heads, hd)
         q = apply_rope(q, pos[None], cfg.rope_theta)
         k = apply_rope(k, pos[None], cfg.rope_theta)
-        pk, pv = pg["k"], pg["v"]
-        n_pt = table_row.shape[0]
-        ps = page_size
-        ctx_k = pk[jnp.maximum(table_row, 0)].reshape(1, n_pt * ps, *pk.shape[2:])
-        ctx_v = pv[jnp.maximum(table_row, 0)].reshape(1, n_pt * ps, *pv.shape[2:])
-        idx = jnp.arange(n_pt * ps, dtype=jnp.int32)
-        mapped = jnp.repeat(table_row >= 0, ps)
-        ctx_pos = jnp.where(mapped & (idx < start), idx, -1)
+        # this layer's pages of the row, gathered in place from the pool
+        ctx_k = _layer_pages(pages["k"], rows, layer)[..., :hd]
+        ctx_v = _layer_pages(pages["v"], rows, layer)[..., :hd]
+        ctx_k = ctx_k.reshape(1, n_pt * ps, cfg.n_kv_heads, hd)
+        ctx_v = ctx_v.reshape(1, n_pt * ps, cfg.n_kv_heads, hd)
         k_all = jnp.concatenate([ctx_k, k], axis=1)
         v_all = jnp.concatenate([ctx_v, v], axis=1)
-        kv_pos = jnp.concatenate([ctx_pos, pos])
         out = masked_attention(q, k_all, v_all, kv_pos, pos, window=window)
         mix = jnp.einsum("bse,ed->bsd", out.reshape(1, T, -1), ap["wo"])
         if cfg.parallel_block:
@@ -880,57 +884,67 @@ def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
         mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h2)
         return x + mlp_out, (k[0], v[0])
 
-    if _paged_stacked(cfg):
-        def body(x, inp):
-            lp, pg = inp
-            x, kv = run_block(x, lp, pg)
-            return x, kv
-
-        x, (k_chunk, v_chunk) = jax.lax.scan(body, x, (params["layers"], pages))
-    else:
-        k_chunk, v_chunk = [], []
-        for lp, pg in zip(params["layers"], pages):
-            x, (kc, vc) = run_block(x, lp, pg)
-            k_chunk.append(kc)
-            v_chunk.append(vc)
-
+    x, (k_chunk, v_chunk) = _paged_layers(cfg, params, x, block)
     x_last = jax.lax.dynamic_index_in_dim(x, valid_len - 1, axis=1, keepdims=False)
     x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
     return _logits(cfg, params, x_last), k_chunk, v_chunk
 
 
-def paged_insert_chunk(cfg: ModelConfig, pages, table_row, start, valid_len,
-                       k_chunk, v_chunk, *, page_size: int):
-    """Scatter a prefill chunk's K/V into the pools through the page table.
-    Padded positions (>= valid_len) and unmapped pages redirect out of
-    bounds and are dropped."""
+def _layer_pages(pool, pages, layer):
+    """pool[pages, layer]: [..., ps, Hkv, W], gathered as whole rows of the
+    pool viewed as [P * L, ps, Hkv, W] — a view that costs nothing in the
+    pool's row-major layout, where a gather over two leading axes would have
+    XLA:TPU relayout the whole pool first."""
+    P, L = pool.shape[:2]
+    return pool.reshape(P * L, *pool.shape[2:])[pages * L + layer]
+
+
+def _write_tokens(pages, phys, off, k, v):
+    """pages[phys[i], :, off[i]] = k[:, i] (and v): token i's K/V of every
+    layer ([L, N, Hkv, hd], zero-padded to the pool's lanes); ``phys`` == P
+    (out of bounds) drops the write.  Jitted with the pool donated, this
+    updates the pool in place."""
+    def put(pool, x):
+        x = jnp.pad(x.swapaxes(0, 1),
+                    [(0, 0)] * 3 + [(0, pool.shape[-1] - x.shape[-1])])
+        return pool.at[phys, :, off].set(x, mode="drop")
+
+    return {"k": put(pages["k"], k), "v": put(pages["v"], v)}
+
+
+def paged_install_decode(pages, table, q_pos, k_new, v_new, *,
+                         page_size: int):
+    """Write a decode step's K/V ([L, B, Hkv, hd], from
+    :func:`paged_decode_step`) at each row's position ``q_pos`` through the
+    page table; rows whose page there is unmapped (idle slots) redirect out
+    of bounds and are dropped — never a wrapped write into page P-1."""
+    table = jnp.asarray(table, jnp.int32)
+    q_pos = jnp.asarray(q_pos, jnp.int32)
+    P = pages["k"].shape[0]
+    phys = jnp.take_along_axis(table, (q_pos // page_size)[:, None], axis=1)[:, 0]
+    phys = jnp.where(phys < 0, P, phys)
+    return _write_tokens(pages, phys, q_pos % page_size, k_new, v_new)
+
+
+def paged_insert_chunk(pages, table_row, start, valid_len, k_chunk, v_chunk,
+                       *, page_size: int):
+    """Scatter a prefill chunk's K/V ([L, T, Hkv, hd]) into the pool through
+    the page table.  Padded positions (>= valid_len) and unmapped pages
+    redirect out of bounds and are dropped."""
     table_row = jnp.asarray(table_row, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     valid_len = jnp.asarray(valid_len, jnp.int32)
-    stacked = _paged_stacked(cfg)
-    T = (k_chunk.shape[1] if stacked else k_chunk[0].shape[0])
-    P = (pages["k"].shape[1] if stacked else pages[0]["k"].shape[0])
-    ps = page_size
+    T = k_chunk.shape[1]
+    P = pages["k"].shape[0]
     idx = start + jnp.arange(T, dtype=jnp.int32)
-    phys = table_row[idx // ps]
-    off = idx % ps
+    phys = table_row[idx // page_size]
     drop = (jnp.arange(T) >= valid_len) | (phys < 0)
     phys = jnp.where(drop, P, phys)
-
-    def ins(pool, upd):
-        return pool.at[phys, off].set(upd, mode="drop")
-
-    if stacked:
-        return {"k": jax.vmap(ins)(pages["k"], k_chunk),
-                "v": jax.vmap(ins)(pages["v"], v_chunk)}
-    return [{"k": ins(pg["k"], kc), "v": ins(pg["v"], vc)}
-            for pg, kc, vc in zip(pages, k_chunk, v_chunk)]
+    return _write_tokens(pages, phys, idx % page_size, k_chunk, v_chunk)
 
 
-def paged_copy_page(cfg: ModelConfig, pages, src, dst):
-    """Copy physical page ``src`` onto ``dst`` in every layer's pools
+def paged_copy_page(pages, src, dst):
+    """Copy physical page ``src`` onto ``dst`` in every layer's pool
     (copy-on-write: a new request that shares only part of a registered
     page copies it and overwrites from its first divergent token)."""
-    if _paged_stacked(cfg):
-        return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), pages)
     return jax.tree.map(lambda a: a.at[dst].set(a[src]), pages)

@@ -2,9 +2,9 @@
 
 :class:`PagedEngine` replaces the per-slot fixed-stride KV cache of
 :class:`~repro.serve.engine.ContinuousEngine` with a **paged** cache
-(``transformer.init_paged_cache``): each layer's K/V lives in a global pool
-of ``n_pages`` physical pages of ``page_size`` tokens, and each request slot
-owns a host-side *page table* mapping logical page indices to physical
+(``transformer.init_paged_cache``): every layer's K/V lives in one global
+pool of ``n_pages`` physical pages of ``page_size`` tokens, and each request
+slot owns a host-side *page table* mapping logical page indices to physical
 pages.  Three things fall out:
 
 * **Memory proportional to live tokens** — a slot holds
@@ -24,10 +24,15 @@ pages.  Three things fall out:
   :class:`~repro.runtime.ExecutorLease`.  A long prompt therefore never
   monopolizes a step: decode latency for active slots — and
   admission-to-first-token for *other* pending prompts — stays bounded by
-  the chunk size, not by the longest prompt in flight.  The chunk graph is
-  read-only over the pools (``transformer.paged_prefill_chunk`` returns the
-  chunk's K/V; the engine scatters it in afterwards), so it coexists with
-  the decode step's page writes without aliasing.
+  the chunk size, not by the longest prompt in flight.
+
+The pool stays in place.  The decode and chunk graphs only read it, by
+(page, layer), and return the K/V they computed
+(``transformer.paged_decode_step``, ``transformer.paged_prefill_chunk``);
+the engine owns every write — the decode step's tokens, each chunk's K/V and
+each copy-on-write — as a small jitted scatter that donates the pool and
+updates it in place, after both graphs of the step have been dispatched
+against the same pre-step pool.
 
 Under **pool exhaustion** the allocator first reclaims cold (refcount-zero)
 registered pages, oldest first; if the pool is still full the engine evicts
@@ -52,6 +57,7 @@ import time
 from collections import OrderedDict, deque
 from contextlib import nullcontext
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +88,7 @@ _UPLOAD = "repro.paged.upload"            # lengths, table, tokens -> device
 _DECODE = "repro.paged.decode"            # the decode graph's host run
 _SAMPLE = "repro.paged.sample"            # dispatch of the sampler
 _READBACK = "repro.paged.readback"        # wait for the sampled tokens
+_INSTALL = "repro.paged.install"          # dispatch of the decode K/V write
 _EMIT = "repro.paged.emit"                # tokens to requests, retirement
 _CHUNK = "repro.paged.chunk"              # one prefill chunk's host run
 _CHUNK_JOIN = "repro.paged.chunk_join"    # wait for the prefill thread
@@ -262,9 +269,10 @@ class PagedEngine(_SamplerMixin):
        then whole younger requests, on exhaustion);
     3. **run** — one decode step over active slots concurrently with one
        prefill chunk per in-flight task, on the step's executor lease;
-    4. **install** — chunk K/V scatters into the pools; a finished prefill
-       registers its pages for sharing, activates its slot, and samples its
-       first token from the chunk logits;
+    4. **install** — the decode step's K/V, then each chunk's, scatter into
+       the donated pool; a finished prefill registers its pages for
+       sharing, activates its slot, and samples its first token from the
+       chunk logits;
     5. **retire** — EOS/budget releases the slot's pages (refcount-zero
        registered pages stay as cold prefix cache).
     """
@@ -325,6 +333,8 @@ class PagedEngine(_SamplerMixin):
             None if pool is not None else default_runtime())
 
         # -- decode graph: fixed shape, calibrated, static host plan --------
+        # (it and the chunk graph read the live pool: neither writes it, so
+        # capture, calibration and warm-up need no copy of it)
         cache_spec = {"len": jnp.zeros((self.capacity,), jnp.int32),
                       "table": jnp.full((self.capacity, self.n_pt), -1, jnp.int32),
                       "pages": self._pages}
@@ -334,7 +344,7 @@ class PagedEngine(_SamplerMixin):
         # necessarily bare CPF — token streams are unchanged (same ops, same
         # numerics; only placements move)
         self._decode_exe = api.compile(
-            make_paged_decode_step(cfg, ps), params, cache_spec, tok_spec,
+            make_paged_decode_step(cfg), params, cache_spec, tok_spec,
             hw=hw, backend="host", jit_nodes=True, host_mode=decode_host_mode,
             pool=pool, runtime=self.runtime, schedule_search=schedule_search,
             name=f"serve_paged_decode[{cfg.name}]",
@@ -347,7 +357,7 @@ class PagedEngine(_SamplerMixin):
             self.profile = self._decode_exe.profile_with(**kw)
         else:
             self.profile = self._decode_exe.calibrate(
-                params, jax.tree.map(jnp.zeros_like, cache_spec),
+                params, cache_spec,
                 jnp.full((self.capacity, 1), scfg.pad_id, jnp.int32),
                 max_executors=max_executors)
         n_exec = self._decode_exe.planned_executors
@@ -375,14 +385,16 @@ class PagedEngine(_SamplerMixin):
             name=f"serve_paged_chunk[{cfg.name},T={self.chunk}]",
         )
 
-        # host-side page maintenance, jitted once with traced indices
+        # every pool write, jitted once with traced indices; each donates
+        # the pool, so it updates the pages it writes in place
+        self._install_decode = jax.jit(
+            partial(transformer.paged_install_decode, page_size=ps),
+            donate_argnums=0)
         self._insert_chunk = jax.jit(
-            lambda pages, row, start, valid, kc, vc:
-            transformer.paged_insert_chunk(cfg, pages, row, start, valid,
-                                           kc, vc, page_size=ps))
-        self._copy_page = jax.jit(
-            lambda pages, src, dst:
-            transformer.paged_copy_page(cfg, pages, src, dst))
+            partial(transformer.paged_insert_chunk, page_size=ps),
+            donate_argnums=0)
+        self._copy_page = jax.jit(transformer.paged_copy_page,
+                                  donate_argnums=0)
 
         self.slots: list[Request | None] = [None] * self.capacity
         self.prefills: dict[int, _PrefillTask] = {}
@@ -400,23 +412,19 @@ class PagedEngine(_SamplerMixin):
         self.n_evictions = 0
         self._process_trace = trace.install()
 
-        # warm every per-step code path against throwaway state
-        warm_pages = jax.tree.map(jnp.zeros_like, self._pages)
-        warm_cache = {"len": jnp.zeros((self.capacity,), jnp.int32),
-                      "table": jnp.full((self.capacity, self.n_pt), -1, jnp.int32),
-                      "pages": warm_pages}
+        # warm every per-step code path on the live pool: with every table
+        # row unmapped the writes all drop, and page 0 copies onto itself
         toks0 = jnp.asarray(self._tokens)
+        unmapped = jnp.full((self.n_pt,), -1, jnp.int32)
         with self._step_pool() as wpool:
-            # only the logits: a warm run's output pool is one more copy
-            # of the pool, and the device has no room to keep it
-            logits = self._run_exe(
-                self._decode_exe, (params, warm_cache, toks0), pool=wpool)[0]
+            logits, kv = self._run_exe(
+                self._decode_exe, (params, cache_spec, toks0), pool=wpool)
             if self._decode_exe.host_mode == "static":
-                self._run_exe(self._decode_exe, (params, warm_cache, toks0),
+                self._run_exe(self._decode_exe, (params, cache_spec, toks0),
                               pool=wpool, host_mode="dynamic")
             chunk_logits, kc, vc = self._run_exe(
                 self._chunk_exe,
-                (params, warm_pages, jnp.full((self.n_pt,), -1, jnp.int32),
+                (params, self._pages, unmapped,
                  {"tokens": jnp.zeros((1, self.chunk), jnp.int32)},
                  jnp.int32(0), jnp.int32(self.chunk)),
                 pool=wpool)
@@ -424,11 +432,13 @@ class PagedEngine(_SamplerMixin):
         for lg in (logits, chunk_logits):
             sample_tokens(lg, cfg.vocab_size, scfg.temperature,
                           jax.random.key(0) if scfg.temperature > 0 else None)
-        warm_pages = self._insert_chunk(
-            warm_pages, jnp.full((self.n_pt,), -1, jnp.int32),
-            jnp.int32(0), jnp.int32(self.chunk), kc, vc)
-        warm_pages = self._copy_page(warm_pages, jnp.int32(0), jnp.int32(0))
-        jax.block_until_ready(jax.tree.leaves(warm_pages)[0])
+        self._pages = self._install_decode(
+            self._pages, cache_spec["table"], cache_spec["len"],
+            kv["k"], kv["v"])
+        self._pages = self._insert_chunk(
+            self._pages, unmapped, jnp.int32(0), jnp.int32(self.chunk), kc, vc)
+        self._pages = self._copy_page(self._pages, jnp.int32(0), jnp.int32(0))
+        jax.block_until_ready(self._pages)
         self._compiles_at_ready = self._process_trace.n_compiles
 
     # -- lifecycle -------------------------------------------------------------
@@ -586,14 +596,16 @@ class PagedEngine(_SamplerMixin):
                             pid, task.tokens, j * ps,
                             min(ps, task.total - j * ps))
             self.slots[slot] = task.req
-        token = int(self._sample(logits)[0])
+        token = int(self._readback(self._dispatch_sample(logits))[0])
         with span(_EMIT):
             self._emit(slot, token)
 
     # -- decode / emit ---------------------------------------------------------
-    def _sample(self, logits) -> np.ndarray:
+    def _dispatch_sample(self, logits) -> jax.Array:
         with span(_SAMPLE):
-            toks = self._sample_on_device(logits)
+            return self._sample_on_device(logits)
+
+    def _readback(self, toks: jax.Array) -> np.ndarray:
         with span(_READBACK):
             return np.asarray(toks, np.int32)
 
@@ -610,10 +622,13 @@ class PagedEngine(_SamplerMixin):
         else:
             self._tokens[slot, 0] = token
 
-    def _decode_once(self, pool, *, overlapping: bool = False) -> None:
+    def _decode_once(self, pool, join=None) -> None:
+        """One decode step.  ``join``, in a step with overlapped chunks,
+        waits until the chunks have been dispatched: they read the pool
+        that the install then donates."""
         # idle rows (free, or mid-prefill) decode against an empty table:
-        # their pool writes redirect out of bounds and drop, their logits
-        # are discarded
+        # their installs redirect out of bounds and drop, their logits are
+        # discarded
         with span(_UPLOAD):
             tbl = self._table.copy()
             ln = self._len.copy()
@@ -625,18 +640,26 @@ class PagedEngine(_SamplerMixin):
                      "pages": self._pages}
             toks = jnp.asarray(self._tokens)
         host_mode = None
-        if overlapping and self._decode_exe.host_mode == "static":
+        if join is not None and self._decode_exe.host_mode == "static":
             # same reasoning as ContinuousEngine: a static plan's segments
             # would serialize the concurrent chunk prefills behind the
             # decode, so overlapped steps fall back to the dynamic scheduler
             host_mode = "dynamic"
         with span(_DECODE):
-            logits, out = self._run_exe(
+            logits, kv = self._run_exe(
                 self._decode_exe, (self.params, cache, toks),
                 pool=pool, host_mode=host_mode)
-        self._pages = out["pages"]
         self.n_decode_steps += 1
-        nxt = self._sample(logits)
+        sampled = self._dispatch_sample(logits)
+        if join is not None:
+            with span(_CHUNK_JOIN):
+                join()
+        # queued behind the sampler, so the device writes the step's K/V
+        # while the host reads the tokens back
+        with span(_INSTALL):
+            self._pages = self._install_decode(
+                self._pages, cache["table"], cache["len"], kv["k"], kv["v"])
+        nxt = self._readback(sampled)
         with span(_EMIT):
             for i in range(self.capacity):
                 if self.slots[i] is not None:
@@ -695,9 +718,8 @@ class PagedEngine(_SamplerMixin):
             toks[0, :T] = task.tokens[task.pos:task.pos + T]
             jobs.append((slot, task, task.pos, T, toks))
         decoding = any(s is not None for s in self.slots)
-        # chunks read the pre-decode page snapshot: their context mask stops
-        # strictly below `start`, so the decode step's concurrent tail writes
-        # can never alias what a chunk reads
+        # chunks and decode read the same pre-step pool; their K/V go in
+        # after both are dispatched (the decode's first, then the chunks')
         pages_in = self._pages
         results = None
         with span(_LEASE):
@@ -718,9 +740,7 @@ class PagedEngine(_SamplerMixin):
                 th = threading.Thread(target=chunk_worker,
                                       name="serve-paged-prefill")
                 th.start()
-                self._decode_once(pool, overlapping=True)
-                with span(_CHUNK_JOIN):
-                    th.join()
+                self._decode_once(pool, join=th.join)
                 if "err" in box:
                     raise box["err"]
                 self.n_overlapped_chunks += len(jobs)
@@ -731,7 +751,7 @@ class PagedEngine(_SamplerMixin):
             elif decoding:
                 self._decode_once(pool)
 
-        # 4. install chunk K/V (disjoint from the decode step's writes) and
+        # 4. install chunk K/V (disjoint from the decode step's tokens) and
         # activate finished prefills
         if results:
             for (slot, task, start, T, _), (logits, kc, vc) in zip(jobs, results):

@@ -40,23 +40,24 @@ def make_decode_step(cfg: ModelConfig) -> Callable:
     return decode_step
 
 
-def make_paged_decode_step(cfg: ModelConfig, page_size: int) -> Callable:
-    """Batched decode over the block-paged KV cache: each batch row reads and
-    writes physical pages through its page-table row (``cache["table"]``);
-    rows whose tail page is unmapped scatter out of bounds and are dropped."""
+def make_paged_decode_step(cfg: ModelConfig) -> Callable:
+    """Batched decode over the block-paged KV cache: each batch row reads
+    physical pages through its page-table row (``cache["table"]``) and the
+    step returns its new K/V, ``[L, B, Hkv, hd]``, in place of a cache — the
+    engine installs them (``transformer.paged_install_decode``), so this
+    graph is read-only over the pool."""
 
     def paged_decode_step(params, cache, tokens):
-        return transformer.paged_decode_step(cfg, params, tokens, cache,
-                                             page_size=page_size)
+        return transformer.paged_decode_step(cfg, params, tokens, cache)
 
     return paged_decode_step
 
 
 def make_prefill_chunk_step(cfg: ModelConfig, page_size: int) -> Callable:
     """One page-aligned prompt chunk of a single request: reads context K/V
-    from the pools (strictly below ``start``), returns the chunk's K/V
+    from the pool (strictly below ``start``), returns the chunk's K/V
     *without writing* — the engine scatters it in afterwards, so this graph
-    can run concurrently with the decode step's pool writes."""
+    and the decode step read the same pool concurrently."""
 
     def prefill_chunk_step(params, pages, table_row, batch, start, valid_len):
         return transformer.paged_prefill_chunk(

@@ -231,7 +231,7 @@ def test_paged_decode_kernel_graph_replays_bit_exact(monkeypatch, scan_layers):
     from repro.serve.step import make_paged_decode_step
 
     cfg, params, cache, tokens, ps = _paged_decode_case(scan_layers)
-    step = make_paged_decode_step(cfg, ps)
+    step = make_paged_decode_step(cfg)
     jnp_logits, jnp_cache = step(params, cache, tokens)
     monkeypatch.setenv("REPRO_USE_PALLAS", "1")
     direct_logits, direct_cache = step(params, cache, tokens)

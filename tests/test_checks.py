@@ -343,7 +343,7 @@ def test_cross_graph_write_write_error():
 
 
 # ---------------------------------------------------------------------------
-# paged decode × prefill chunk: the PR 6 concurrency protocol, certified
+# paged decode × prefill chunk: both read the one pool, neither writes it
 # ---------------------------------------------------------------------------
 
 def test_paged_pair_has_zero_write_conflicts():
@@ -359,7 +359,7 @@ def test_paged_pair_has_zero_write_conflicts():
                   "table": jnp.full((B, n_pt), -1, jnp.int32),
                   "pages": pages}
     tok = jnp.zeros((B, 1), jnp.int32)
-    dec = repro.compile(make_paged_decode_step(cfg, page), params,
+    dec = repro.compile(make_paged_decode_step(cfg), params,
                         cache_spec, tok, name="chk.paged_decode")
     row = jnp.full((n_pt,), -1, jnp.int32)
     batch = {"tokens": jnp.zeros((1, page), jnp.int32)}
@@ -375,15 +375,51 @@ def test_paged_pair_has_zero_write_conflicts():
     pool_ids = {id(x) for x in jax.tree.leaves(pages)}
     pool_shared = [(a, b) for a, b in shared if id(bind_d[a]) in pool_ids]
 
-    # decode writes the pools (the scan-body scatters were traced) ...
     assert pool_shared, "alias discovery found no shared pool buffers"
-    assert eff_d.written() & {a for a, _ in pool_shared}
-    # ... the chunk graph is certified read-only over every shared pool
+    # the decode step returns its K/V for the engine to install: it is
+    # certified read-only over the pool, and so is the chunk graph
+    assert eff_d.read_only(a for a, _ in pool_shared)
     assert eff_c.read_only(b for _, b in pool_shared)
     # ... so the pair has zero unordered write/write conflicts
     rep = cross_graph_hazards(eff_d, eff_c, shared)
     assert not any(f.rule_id == "H-XWW" for f in rep.findings)
     assert rep.ok
+
+
+@pytest.mark.parametrize("writes", [False, True], ids=["read_only", "writes_pool"])
+def test_zoo_paged_rule_requires_both_graphs_read_only(monkeypatch, writes):
+    """``python -m repro.checks --zoo``'s paged rule certifies the decode
+    step and the prefill chunk read-only over the pool (C-RO for each), and
+    reports an error for a decode step that writes the pool."""
+    import repro.serve.step as serve_step
+    from repro.checks.cli import run_zoo_arch
+
+    if writes:
+        real = serve_step.make_paged_decode_step
+
+        def writing(cfg):
+            step = real(cfg)
+
+            def paged_decode_step(params, cache, tokens):
+                logits, kv = step(params, cache, tokens)
+                pool = cache["pages"]
+                return logits, {n: pool[n].at[0, :, 0, :, :kv[n].shape[-1]]
+                                .set(kv[n][:, 0]) for n in ("k", "v")}
+
+            return paged_decode_step
+
+        monkeypatch.setattr(serve_step, "make_paged_decode_step", writing)
+    rep = run_zoo_arch("h2o-danube3-4b")
+    paged = [f for f in rep.findings if f.where.endswith("/paged")]
+    certified = {f.message.split(" certified")[0] for f in paged
+                 if f.rule_id == "C-RO"}
+    errors = [f.message for f in paged if f.severity == "error"]
+    if writes:
+        assert certified == {"prefill chunk"}
+        assert any("paged decode writes a pool buffer" in m for m in errors)
+    else:
+        assert certified == {"paged decode", "prefill chunk"}
+        assert not errors and rep.ok
 
 
 # ---------------------------------------------------------------------------

@@ -114,42 +114,94 @@ def test_decode_attention_ring_buffer():
 # paged decode attention
 # ---------------------------------------------------------------------------
 
-def _paged_case(key, B, P, ps, n_pt, Hq, Hkv, hd, lens, dt):
-    ks = jax.random.split(key, 3)
+def _paged_case(key, B, L, P, ps, n_pt, Hq, Hkv, hd, q_pos, mapped, dt):
+    """A pool [P, L, ps, Hkv, hd]; row b maps ``mapped[b]``
+    distinct pages and decodes its own token at ``q_pos[b]``."""
+    ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (B, Hq, hd), dt)
-    kp = jax.random.normal(ks[1], (P, ps, Hkv, hd), dt)
-    vp = jax.random.normal(ks[2], (P, ps, Hkv, hd), dt)
-    # each row maps ceil(len/ps) distinct pages, rest unmapped
+    kp = jax.random.normal(ks[1], (P, L, ps, Hkv, hd), dt)
+    vp = jax.random.normal(ks[2], (P, L, ps, Hkv, hd), dt)
+    k_new = jax.random.normal(ks[3], (B, Hkv, hd), dt)
+    v_new = jax.random.normal(ks[4], (B, Hkv, hd), dt)
     table = np.full((B, n_pt), -1, np.int32)
     nxt = 0
-    for b, n in enumerate(lens):
-        for j in range(-(-n // ps)):
+    for b, n in enumerate(mapped):
+        for j in range(n):
             table[b, j] = nxt % P
             nxt += 1
-    q_pos = jnp.asarray([n - 1 for n in lens], jnp.int32)
-    return q, kp, vp, jnp.asarray(table), q_pos
+    return (q, k_new, v_new, kp, vp, jnp.asarray(table),
+            jnp.asarray(q_pos, jnp.int32))
+
+
+def _seeded_ref(q, k_new, v_new, k_pages, v_pages, table, q_pos, window):
+    """ref.py on the layer's pool with each row's own token written in at
+    q_pos; a row whose page there is unmapped gets a scratch page."""
+    kp, vp = np.array(k_pages), np.array(v_pages)
+    table = np.asarray(table).copy()
+    ps = kp.shape[1]
+    for b, pos in enumerate(np.asarray(q_pos)):
+        j, t = divmod(int(pos), ps)
+        if table[b, j] < 0:
+            table[b, j] = len(kp)
+            kp = np.concatenate([kp, np.zeros_like(kp[:1])])
+            vp = np.concatenate([vp, np.zeros_like(vp[:1])])
+        kp[table[b, j], t] = np.asarray(k_new[b])
+        vp[table[b, j], t] = np.asarray(v_new[b])
+    return paged_decode_attention_ref(q, jnp.asarray(kp), jnp.asarray(vp),
+                                      jnp.asarray(table), q_pos, window=window)
 
 
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "B,P,ps,n_pt,Hq,Hkv,hd,lens,window",
+    "B,P,ps,n_pt,Hq,Hkv,hd,q_pos,mapped,window",
     [
-        (2, 16, 16, 4, 8, 1, 64, (50, 17), None),   # MQA, mixed fill
-        (1, 8, 32, 3, 4, 2, 32, (70,), 48),         # GQA sliding window
-        (3, 12, 8, 6, 4, 4, 16, (48, 1, 23), None), # MHA, full/empty rows
+        # MQA: a row past its live pages, a token on a page boundary, idle
+        (3, 16, 16, 4, 8, 1, 64, (49, 16, 0), (4, 2, 0), None),
+        # GQA sliding window
+        (1, 8, 32, 3, 4, 2, 32, (69,), (3,), 48),
+        # MHA: full row, a token on an unmapped tail page, a mid-page token
+        (3, 12, 8, 6, 4, 4, 16, (47, 24, 22), (6, 3, 3), None),
     ],
 )
-def test_paged_decode_attention(B, P, ps, n_pt, Hq, Hkv, hd, lens, window, dt):
-    q, kp, vp, table, q_pos = _paged_case(
-        jax.random.key(10), B, P, ps, n_pt, Hq, Hkv, hd, lens, dt)
-    out = paged_decode_attention(q, kp, vp, table, q_pos, window=window,
-                                 use_kernel=True, interpret=True)
-    ref = paged_decode_attention_ref(q, kp, vp, table, q_pos, window=window)
+def test_paged_decode_attention(B, P, ps, n_pt, Hq, Hkv, hd, q_pos, mapped,
+                                window, dt):
+    q, k_new, v_new, kp, vp, table, qp = _paged_case(
+        jax.random.key(10), B, 2, P, ps, n_pt, Hq, Hkv, hd, q_pos, mapped, dt)
+    layer = jnp.int32(1)
+    ref = _seeded_ref(q, k_new, v_new, kp[:, 1], vp[:, 1], table, qp, window)
+    out = paged_decode_attention(q, k_new, v_new, kp, vp, layer, table, qp,
+                                 window=window, use_kernel=True, interpret=True)
     allclose(out, ref, dt)
     # the jnp fallback path must agree too (it is what captured graphs run)
-    jnp_out = paged_decode_attention(q, kp, vp, table, q_pos, window=window,
-                                     use_kernel=False)
+    jnp_out = paged_decode_attention(q, k_new, v_new, kp, vp, layer, table,
+                                     qp, window=window, use_kernel=False)
     allclose(jnp_out, ref, dt)
+
+
+def test_paged_decode_reads_only_its_layer():
+    """Changing another layer's pages, or pool positions at or after
+    q_pos, changes nothing: the pool is read by (layer, page) and strictly
+    below each row's own token."""
+    q, k_new, v_new, kp, vp, table, qp = _paged_case(
+        jax.random.key(12), 2, 3, 8, 8, 3, 4, 2, 16, (13, 5), (3, 1),
+        jnp.float32)
+    for use_kernel in (True, False):
+        def run(kp, vp):
+            return paged_decode_attention(q, k_new, v_new, kp, vp,
+                                          jnp.int32(1), table, qp,
+                                          use_kernel=use_kernel,
+                                          interpret=True)
+        base = run(kp, vp)
+        noisy = kp.at[:, 0].set(-kp[:, 0]).at[:, 2].set(kp[:, 2] * 3)
+        np.testing.assert_array_equal(np.asarray(run(noisy, vp)),
+                                      np.asarray(base))
+        # row 0's positions 13.. (page 1, offsets 5..7; page 2) and row 1's
+        # 5.. (page 3, offsets 5..7) are not yet written in the engine
+        tbl = np.asarray(table)
+        stale = (kp.at[tbl[0, 1], 1, 5:].set(9.0).at[tbl[0, 2], 1].set(9.0)
+                 .at[tbl[1, 0], 1, 5:].set(9.0))
+        np.testing.assert_array_equal(np.asarray(run(stale, vp)),
+                                      np.asarray(base))
 
 
 def test_paged_matches_linear_decode_attention():
@@ -163,12 +215,14 @@ def test_paged_matches_linear_decode_attention():
     kv_pos = jnp.where(jnp.arange(S) < fill, jnp.arange(S), -1)
     q_pos = jnp.asarray(fill - 1, jnp.int32)
     ref = decode_attention_ref(q, kc, vc, kv_pos, q_pos)
-    # repack row b's cache as pages b*n_pt + j
+    # repack row b's cache as pages b*n_pt + j of layer 0; the token at
+    # fill-1 is the row's own, the pool holds what lies before it
     n_pt = S // ps
-    kp = kc.reshape(B * n_pt, ps, H, hd)
-    vp = vc.reshape(B * n_pt, ps, H, hd)
+    kp = kc.reshape(B * n_pt, 1, ps, H, hd)
+    vp = vc.reshape(B * n_pt, 1, ps, H, hd)
     table = jnp.arange(B * n_pt, dtype=jnp.int32).reshape(B, n_pt)
-    out = paged_decode_attention(q, kp, vp, table,
+    out = paged_decode_attention(q, kc[:, fill - 1], vc[:, fill - 1], kp, vp,
+                                 jnp.int32(0), table,
                                  jnp.full((B,), fill - 1, jnp.int32),
                                  use_kernel=True, interpret=True)
     allclose(out, ref, jnp.float32)

@@ -275,3 +275,64 @@ def test_repeated_eviction_under_sustained_pressure_stays_exact(model):
     for r in sorted(done, key=lambda r: r.request_id):
         assert r.output == _reference_decode(cfg, params, prompts[r.request_id], 6), \
             f"request {r.request_id} diverged after eviction/recompute"
+
+
+# ---------------------------------------------------------------------------
+# the pool stays in place: every write donates it
+# ---------------------------------------------------------------------------
+
+def test_overlapped_chunks_and_cow_stay_exact(model):
+    """Shared prefixes that diverge mid-page (copy-on-write) admitted while
+    other requests decode (chunks overlapped with the decode step, both
+    reading one pool): every stream matches unbatched greedy."""
+    cfg, params = model
+    rng = np.random.default_rng(5)
+    base = rng.integers(1, cfg.vocab_size, size=20).astype(np.int32)
+    prompts = [base]
+    for cut in (19, 13):                       # diverge inside pages 2 and 1
+        p = base.copy()
+        p[cut] = int(base[cut] % (cfg.vocab_size - 1)) + 1
+        prompts.append(np.concatenate(
+            [p, rng.integers(1, cfg.vocab_size, size=9).astype(np.int32)]))
+    with PagedEngine(cfg, params, ServeConfig(max_batch=3, max_len=48),
+                     paged=PagedConfig(page_size=8, prefill_chunk=8)) as eng:
+        first = Request(request_id=0, prompt=prompts[0], max_new_tokens=10)
+        eng.submit(first)
+        while not first.output:
+            eng.step()
+        for i, p in enumerate(prompts[1:], start=1):
+            eng.submit(Request(request_id=i, prompt=p, max_new_tokens=6))
+        done = eng.run()
+        assert eng.n_cow_copies >= 2 and eng.n_overlapped_chunks > 0
+    for r in done:
+        assert r.output == _reference_decode(
+            cfg, params, r.prompt, r.max_new_tokens), r.request_id
+
+
+def test_pool_writes_donate_the_pool(model):
+    """Each pool write — a chunk insert, the decode step's install, a
+    copy-on-write — consumes the engine's pool: the array it held is
+    deleted and the engine holds only the updated one."""
+    cfg, params = model
+    rng = np.random.default_rng(6)
+    prompt = rng.integers(1, cfg.vocab_size, size=12).astype(np.int32)
+    with PagedEngine(cfg, params, ServeConfig(max_batch=2, max_len=48),
+                     paged=PagedConfig(page_size=8, prefill_chunk=8)) as eng:
+        def step_replaces_pool() -> None:
+            held = jax.tree.leaves(eng._pages)
+            eng.step()
+            assert all(a.is_deleted() for a in held)
+            assert not any(a.is_deleted() for a in jax.tree.leaves(eng._pages))
+
+        eng.submit(Request(request_id=0, prompt=prompt, max_new_tokens=4))
+        step_replaces_pool()                   # chunk insert
+        step_replaces_pool()                   # chunk insert, first token
+        step_replaces_pool()                   # decode install
+        assert eng.n_decode_steps == 1
+        eng.run()
+        cow = prompt.copy()
+        cow[10] = cow[10] % (cfg.vocab_size - 1) + 1   # diverge in page 1
+        eng.submit(Request(request_id=1, prompt=cow, max_new_tokens=2))
+        step_replaces_pool()                   # copy-on-write, then insert
+        assert eng.n_cow_copies == 1
+        eng.run()
