@@ -1,18 +1,19 @@
 """The comparison that decides ``correct``, against the plain reference.
 
-Serving: for each sampled request the reference runs once over its prompt
-and the tokens the engine served, and each served token's logit is read
-against the reference's best at its position.  The number compared is the
-widest such gap over the sample (0 where every served token is the
-reference's first choice).  The reference runs layer by layer, one request
-at a time, after the engine and its cache are gone, so it fits beside the
-weights.
+The reference is the forward pass of the module that the configuration
+names under ``"reference"`` (``spec.architecture``).  Serving: for each
+sampled request the reference runs once over its prompt and the tokens the
+engine served, and each served token's logit is read against the
+reference's best at its position.  The number compared is the widest such
+gap over the sample (0 where every served token is the reference's first
+choice).  The reference runs layer by layer, one request at a time, after
+the engine and its cache are gone, so it fits beside the weights.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from bench.reference import dense_gqa as ref
+from .spec import architecture
 
 
 def _bucket(n: int, step: int = 128) -> int:
@@ -30,12 +31,13 @@ class Reference:
         import jax.numpy as jnp
 
         self.m, self.params, self.length = m, params, _bucket(length)
+        ref = architecture(m)
 
         def layer(layers, i, x, pos):
             lp = jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
                 layers)
-            return ref.block(m, lp, x, pos, mode)
+            return ref.block(m, lp, i, x, pos, mode)
 
         self._layer = jax.jit(layer)
         self._embed = jax.jit(ref.embed)

@@ -53,18 +53,42 @@ class ServeRecord:
     events: object = None                              # trace.Events, traced runs
 
 
+# keys of a configuration file that describe it and size nothing
+DESCRIPTIVE = ("source", "reference", "reduced", "published", "assumed",
+               "deployment")
+
+
 def model_config(m: dict):
-    """The program's ``ModelConfig`` for configuration file ``m``."""
+    """The program's ``ModelConfig`` for configuration file ``m``: every key
+    of the file that is a ``ModelConfig`` field (``family`` "dense" unless
+    the file says), past the descriptive keys and those the architecture
+    module reads itself (its ``OWN_KEYS``).  Any other key raises, so that
+    a misspelt size fails instead of being dropped."""
+    import dataclasses
+
     import jax.numpy as jnp
 
     from repro.configs.base import ModelConfig
 
-    keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_ff",
-            "vocab_size", "head_dim", "sliding_window", "rope_theta",
-            "norm_eps", "act", "tie_embeddings")
-    return ModelConfig(name=m["name"], family="dense",
-                       dtype=getattr(jnp, m["dtype"]),
-                       **{k: m[k] for k in keys if k in m})
+    from .spec import architecture
+
+    fields = {f.name: f for f in dataclasses.fields(ModelConfig)}
+    skip = set(DESCRIPTIVE) | set(getattr(architecture(m), "OWN_KEYS", ()))
+    unknown = sorted(k for k in m if k not in fields and k not in skip)
+    if unknown:
+        raise KeyError(f"configuration {m.get('name')!r}: keys {unknown} are "
+                       f"neither ModelConfig fields nor read by "
+                       f"{m['reference']}")
+    kw = {"family": "dense"}
+    for k, v in m.items():
+        if k in skip:
+            continue
+        if k == "dtype":
+            v = getattr(jnp, v)
+        elif isinstance(v, list) and str(fields[k].type).startswith("tuple"):
+            v = tuple(v)
+        kw[k] = v
+    return ModelConfig(**kw)
 
 
 class Loop:

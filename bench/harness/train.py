@@ -177,6 +177,7 @@ def reference(m: dict, opt: dict, key, batches, devices, mode: str = "f32",
     from bench.reference import adamw_ref
 
     from . import weights
+    from .spec import architecture
 
     n = len(devices)
     mesh = Mesh(np.array(devices), ("d",))
@@ -190,14 +191,15 @@ def reference(m: dict, opt: dict, key, batches, devices, mode: str = "f32",
                 return NamedSharding(mesh, P(*([None] * i + ["d"])))
         return rep
 
-    p_sh = jax.tree.map(split, weights.shapes(m),
-                        is_leaf=lambda s: isinstance(s, tuple))
+    p_sh = jax.tree.map(lambda s: split(s.shape), weights.specs(m))
     rows_sh = by_row if len(batches[0]["tokens"][rows]) % n == 0 else rep
     hooks = (lambda lp: jax.tree.map(
                  lambda a: jax.lax.with_sharding_constraint(a, rep), lp),
              lambda x: jax.lax.with_sharding_constraint(x, rows_sh))
+    arch = architecture(m)
     grad = jax.jit(lambda p, tok, lab: adamw_ref.grads(
-        m, p, tok, lab, mode=mode, hooks=hooks), out_shardings=(rep, p_sh))
+        arch, m, p, tok, lab, mode=mode, hooks=hooks),
+        out_shardings=(rep, p_sh))
     b1, b2 = opt["b1"], opt["b2"]
 
     def first(p, g):

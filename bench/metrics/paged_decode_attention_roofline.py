@@ -1,7 +1,8 @@
 """The paged decode-attention kernel's share of its roofline: the least time
 the chip could take for the attention of every decode step in the traced
-window (one call per layer; ``harness/flops.py``), over the summed device
-self time of the kernel's events (%)."""
+window (one call per attention layer, at that layer's window;
+``harness/flops.py``), over the summed device self time of the kernel's
+events (%)."""
 from bench.harness import flops, serve
 
 KERNEL = "paged_decode_attention"
@@ -13,11 +14,9 @@ def read(run, peaks):
     spent = run.trace.time_of(KERNEL)
     if spent <= 0:
         return None
-    m = run.model
     least = 0.0
     for s in serve.window_steps(run):
         if s.decoded and s.ctx:
-            f, b = flops.paged_decode_attention_cost(m, s.ctx)
-            least += m["n_layers"] * flops.least_time(f, b, peaks.flops,
-                                                      peaks.hbm_bw)
+            least += flops.paged_decode_attention_least_time(
+                run.model, s.ctx, peaks.flops, peaks.hbm_bw)
     return 100.0 * least / spent

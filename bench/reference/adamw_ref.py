@@ -1,10 +1,12 @@
-"""Plain reference of a training step: the dense decoder's mean next-token
-cross-entropy (``dense_gqa``), its gradient, global-norm clipping and AdamW
-with decoupled weight decay (Loshchilov & Hutter), bias-corrected moments,
-and a learning rate that warms up linearly and then follows a cosine down
-to a tenth.  Every operation is float32; parameters are kept in the type
-the configuration states.  Decay applies to weight matrices, not to norm
-gains.  Nothing of the program under test is imported.
+"""Plain reference of a training step: a decoder's mean next-token
+cross-entropy (the forward pass of ``arch``, the architecture module that
+the configuration names, such as ``dense_gqa``), its gradient, global-norm
+clipping and AdamW with decoupled weight decay (Loshchilov & Hutter),
+bias-corrected moments, and a learning rate that warms up linearly and then
+follows a cosine down to a tenth.  Every operation is float32; parameters
+are kept in the type the configuration states.  Decay applies to weight
+matrices, not to norm gains.  Nothing of the program under test is
+imported.
 
 ``hooks``, where given, is a pair of functions applied to each layer's
 weights and to the activations between layers: placement hints for a
@@ -17,8 +19,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from . import dense_gqa
-
 GAINS = ("ln1", "ln2", "final_norm")
 
 
@@ -26,27 +26,30 @@ def _name(path) -> str:
     return str(getattr(path[-1], "key", path[-1]))
 
 
-def loss(m: dict, params, tokens, labels, mode: str = "f32", hooks=None):
+def loss(arch, m: dict, params, tokens, labels, mode: str = "f32",
+         hooks=None):
     """Mean next-token cross-entropy over every label; the layers swept by
     ``lax.scan``, each recomputed on the backward pass."""
     on_layer, on_x = hooks or ((lambda lp: lp), (lambda x: x))
     pos = jnp.arange(tokens.shape[1])
-    x = on_x(dense_gqa.embed(params, tokens))
+    x = on_x(arch.embed(params, tokens))
 
     @jax.checkpoint
-    def body(x, lp):
-        return on_x(dense_gqa.block(m, on_layer(lp), x, pos, mode)), None
+    def body(x, layer):
+        lp, i = layer
+        return on_x(arch.block(m, on_layer(lp), i, x, pos, mode)), None
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    logits = dense_gqa.head(m, params, x, mode)
+    x, _ = jax.lax.scan(body, x, (params["layers"], jnp.arange(m["n_layers"])))
+    logits = arch.head(m, params, x, mode)
     logp = jax.nn.log_softmax(logits, axis=-1)
     return -jnp.mean(jnp.take_along_axis(logp, labels[..., None], axis=-1))
 
 
-def grads(m: dict, params, tokens, labels, mode: str = "f32", hooks=None):
+def grads(arch, m: dict, params, tokens, labels, mode: str = "f32",
+          hooks=None):
     """(loss, gradient in float32)."""
     value, g = jax.value_and_grad(
-        lambda p: loss(m, p, tokens, labels, mode, hooks))(params)
+        lambda p: loss(arch, m, p, tokens, labels, mode, hooks))(params)
     return value, jax.tree.map(lambda x: x.astype(jnp.float32), g)
 
 

@@ -4,9 +4,14 @@ each head, causal (optionally windowed) softmax attention, SwiGLU
 feed-forward, an untied output head.  Straight ``jax.numpy`` in float32 at
 ``Precision.HIGHEST``; nothing of the program under test is imported.
 
-Weights are read in the layout the benchmark makes them
-(``bench/harness/weights.py``).  One departure from the published form: a
-norm's gain is stored as ``scale`` and applied as ``1 + scale``.
+This module is the architecture that a configuration file names under
+``"reference"``: besides the forward pass it gives the benchmark the weight
+tree (``shapes``), the weights a token multiplies through
+(``matmul_params``) and each attention layer's window
+(``attention_windows``).  Weights are read in the layout the benchmark
+makes them (``bench/harness/weights.py``).  One departure from the
+published form: a norm's gain is stored as ``scale`` and applied as
+``1 + scale``.
 
 ``mode="fp8"`` is the control: every matrix product takes its operands
 rounded to float8 e4m3 (a scale per row of the left operand and per column
@@ -22,6 +27,38 @@ import jax
 import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def head_dim(m: dict) -> int:
+    return int(m.get("head_dim") or m["d_model"] // m["n_heads"])
+
+
+def shapes(m: dict) -> dict:
+    """The weight tree: each leaf's shape, layers stacked on a leading axis."""
+    L, d, f, V = m["n_layers"], m["d_model"], m["d_ff"], m["vocab_size"]
+    hd, H, Hkv = head_dim(m), m["n_heads"], m["n_kv_heads"]
+    return {
+        "embed": (V, d), "final_norm": (d,), "unembed": (d, V),
+        "layers": {
+            "ln1": (L, d), "ln2": (L, d),
+            "attn": {"wq": (L, d, H * hd), "wk": (L, d, Hkv * hd),
+                     "wv": (L, d, Hkv * hd), "wo": (L, H * hd, d)},
+            "mlp": {"w_gate": (L, d, f), "w_up": (L, d, f), "w_down": (L, f, d)},
+        },
+    }
+
+
+def matmul_params(m: dict) -> int:
+    """Weights one token multiplies through: q, k, v and o, the SwiGLU's
+    three matrices in every layer, and the head."""
+    d, f, hd = m["d_model"], m["d_ff"], head_dim(m)
+    attn = d * hd * (m["n_heads"] + 2 * m["n_kv_heads"]) + m["n_heads"] * hd * d
+    return m["n_layers"] * (attn + 3 * d * f) + d * m["vocab_size"]
+
+
+def attention_windows(m: dict) -> list:
+    """Each attention layer's window (None: the whole context)."""
+    return [m.get("sliding_window")] * m["n_layers"]
 
 
 def _round(a, axis, dtype, top: float):
@@ -115,11 +152,12 @@ def attention(q, k, v, pos, window, mode: str):
     return o.transpose(1, 2, 0, 3, 4).reshape(B, S, H * hd)
 
 
-def block(m: dict, lp: dict, x, pos, mode: str = "f32"):
-    """One decoder layer over a batch of sequences. x: [B, S, d] float32."""
+def block(m: dict, lp: dict, i, x, pos, mode: str = "f32"):
+    """Decoder layer ``i`` (weights ``lp``) over a batch of sequences; every
+    layer is alike here, so ``i`` is not read.  x: [B, S, d] float32."""
     B, S, _ = x.shape
     H, Hkv = m["n_heads"], m["n_kv_heads"]
-    hd = m.get("head_dim") or m["d_model"] // H
+    hd = head_dim(m)
     a = lp["attn"]
     h = rms_norm(x, lp["ln1"], m["norm_eps"])
     q = mm("bsd,de->bse", h, a["wq"], mode).reshape(B, S, H, hd)
@@ -157,7 +195,7 @@ def forward(m: dict, params, tokens, mode: str = "f32"):
     pos = jnp.arange(tokens.shape[1])
     x = embed(params, tokens)
     for i in range(m["n_layers"]):
-        x = block(m, layer(params, i), x, pos, mode)
+        x = block(m, layer(params, i), i, x, pos, mode)
     return head(m, params, x, mode)
 
 

@@ -17,7 +17,6 @@ number compared beside its limit.  The same numbers end standard error.
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import os
 import sys
@@ -47,11 +46,10 @@ def _log(msg: str) -> None:
 
 def reader(name: str, root: Path):
     """The ``read`` function of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    from bench.harness.spec import import_file
+
+    return import_file(root / "bench" / "metrics" / f"{name}.py",
+                       f"bench_metric_{name}").read
 
 
 def enable_compile_cache(root: Path) -> str:
