@@ -15,6 +15,9 @@ import jax.numpy as jnp
 __all__ = ["ModelConfig", "get_config", "list_archs", "SHAPES", "shape_for"]
 
 
+_ATTENTION_TYPES = ("sliding_attention", "full_attention")
+
+
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
@@ -38,10 +41,21 @@ class ModelConfig:
     block_pattern: tuple[str, ...] = ("attn",)
     parallel_block: bool = False     # command-r: attn and FFN in parallel
     rope_theta: float = 10_000.0
+    # per-layer attention, as HF's ``layer_types``: "sliding_attention"
+    # layers attend within ``sliding_window``, "full_attention" layers over
+    # the whole context; empty: every layer attends within sliding_window
+    layer_types: tuple[str, ...] = ()
+    # YaRN rope scaling of the full-attention layers (HF ``rope_type:
+    # yarn``, beta_fast 32, beta_slow 1, attention factor 0.1 ln(factor) + 1;
+    # factor 0 = plain rope)
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0               # experts held: the weights' leading axis
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training's capacity routing only
+    router_experts: int = 0          # router width; 0 -> n_experts (all held)
+    first_expert: int = 0            # router index of the first held expert
     # --- SSM (mamba) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -62,6 +76,43 @@ class ModelConfig:
     dtype: Any = jnp.bfloat16
     # --- bookkeeping ---
     source: str = ""                 # provenance note
+
+    def __post_init__(self):
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(f"{self.name}: {len(self.layer_types)} layer_types "
+                             f"for {self.n_layers} layers")
+        unknown = set(self.layer_types) - set(_ATTENTION_TYPES)
+        if unknown:
+            raise ValueError(f"{self.name}: unknown layer_types {sorted(unknown)}")
+        if self.first_expert + self.n_experts > self.routed_experts:
+            raise ValueError(f"{self.name}: experts {self.first_expert}.."
+                             f"{self.first_expert + self.n_experts - 1} held "
+                             f"of {self.routed_experts} routed")
+
+    @property
+    def routed_experts(self) -> int:
+        """Experts the router chooses among (all of them, held or not)."""
+        return self.router_experts or self.n_experts
+
+    def attention_types(self) -> tuple[str, ...]:
+        """Each layer's attention type (``layer_types``, or every layer
+        sliding when ``sliding_window`` is set and full when not)."""
+        if self.layer_types:
+            return self.layer_types
+        kind = "sliding_attention" if self.sliding_window else "full_attention"
+        return (kind,) * self.n_layers
+
+    def layer_windows(self) -> tuple[int, ...]:
+        """Each layer's attention window; 0 for the whole context."""
+        w = self.sliding_window or 0
+        return tuple(w if t == "sliding_attention" else 0
+                     for t in self.attention_types())
+
+    def layer_yarn(self) -> tuple[bool, ...]:
+        """Whether each layer's rope is YaRN-scaled (the full ones, when
+        ``yarn_factor`` is set)."""
+        return tuple(self.yarn_factor > 0 and t == "full_attention"
+                     for t in self.attention_types())
 
     @property
     def resolved_head_dim(self) -> int:
@@ -105,7 +156,7 @@ class ModelConfig:
                 attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
                 ffn = 3 * d * f if self.act in ("silu", "gelu") else 2 * d * f
                 if self.n_experts:
-                    ffn = self.n_experts * 3 * d * f + d * self.n_experts
+                    ffn = self.n_experts * 3 * d * f + d * self.routed_experts
                 total += attn + ffn + 2 * d
             elif kind == "ssm":
                 di, st, dr = self.d_inner, self.ssm_state, self.dt_rank
@@ -122,12 +173,13 @@ class ModelConfig:
         return int(total)
 
     def active_params(self) -> int:
-        """Active (per-token) parameters — MoE counts top_k experts only."""
+        """Active (per-token) parameters — MoE counts the top_k experts a
+        token expects among those held."""
         if not self.n_experts:
             return self.n_params()
         d, f = self.d_model, self.d_ff
         dense_moe = self.n_experts * 3 * d * f
-        active_moe = self.top_k * 3 * d * f
+        active_moe = self.top_k * self.n_experts / self.routed_experts * 3 * d * f
         return int(self.n_params() - self.n_layers * (dense_moe - active_moe))
 
     def reduced(self, **overrides: Any) -> "ModelConfig":
@@ -165,6 +217,7 @@ _ARCHS = [
     "llava_next_34b",
     "olmoe_1b_7b",
     "granite_moe_1b_a400m",
+    "mellum2_12b_a2_5b",
     "whisper_medium",
     "falcon_mamba_7b",
     "recurrentgemma_2b",
@@ -178,6 +231,6 @@ def list_archs(include_paper: bool = False) -> list[str]:
 
 
 def get_config(name: str, *, smoke: bool = False) -> ModelConfig:
-    key = name.replace("-", "_")
+    key = name.replace("-", "_").replace(".", "_")
     mod = importlib.import_module(f"repro.configs.{key}")
     return mod.SMOKE if smoke else mod.CONFIG

@@ -130,7 +130,8 @@ def decode_attention_kernel_call(
 # page *table*.  The layer and the table are scalar-prefetch operands
 # (PrefetchScalarGridSpec), so the BlockSpec index map itself chases them:
 # grid step (b, ip) DMAs layer `layer` of page table[b, ip] straight out of
-# the pool — the kernel never materializes a gathered [B, S] cache, nor a
+# the pool; the layer's window (0 = the whole context) is one too, so one
+# compiled kernel serves every layer of a scan — the kernel never materializes a gathered [B, S] cache, nor a
 # slice of one layer's pool.  Unmapped entries (table[b, ip] < 0) clamp to page 0 for the DMA and
 # are masked out of the online softmax in the body.
 #
@@ -140,9 +141,10 @@ def decode_attention_kernel_call(
 # ---------------------------------------------------------------------------
 
 def _paged_kernel(
-    layer_ref, table_ref, qpos_ref,      # scalar-prefetch: [1], [B, n_pt], [B]
+    layer_ref, window_ref, table_ref, qpos_ref,  # scalar-prefetch: [1], [1],
+                                                 # [B, n_pt], [B]
     q_ref, knew_ref, vnew_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-    *, n_pt: int, ps: int, G: int, window: int | None, scale: float,
+    *, n_pt: int, ps: int, G: int, scale: float,
 ):
     del layer_ref                        # consumed by the index maps
     b = pl.program_id(0)
@@ -172,9 +174,7 @@ def _paged_kernel(
         (((2,), (2,)), ((0,), (1,))),
         preferred_element_type=jnp.float32,
     )                                            # [Hkv, G, ps]
-    keep = (page >= 0) & (kv_pos < q_pos)
-    if window is not None:
-        keep &= kv_pos > q_pos - window
+    keep = (page >= 0) & (kv_pos < q_pos) & (kv_pos > q_pos - window_ref[0])
     s = jnp.where(keep[None, None, :], s, _NEG_INF)
 
     sm = s.reshape(Hq, ps)
@@ -205,10 +205,10 @@ def paged_decode_attention_kernel_call(
     k_pages: jax.Array,     # [P, L, ps, Hkv, W], W >= hd (lane-padded)
     v_pages: jax.Array,
     layer: jax.Array,       # [] int32
+    window: jax.Array,      # [] int32, 0 = the whole context
     page_table: jax.Array,  # [B, n_pt] int32, -1 = unmapped
     q_pos: jax.Array,       # [B] int32
     *,
-    window: int | None,
     interpret: bool,
 ) -> jax.Array:
     B, Hq, hd = q.shape
@@ -221,21 +221,25 @@ def paged_decode_attention_kernel_call(
     # nothing to q.k or to the output, which drops them); the fresh K/V
     # come once per query head, so the seed is a plain product with q (a
     # few KB a row, against a page walk of megabytes)
+    # no window: one wider than any position, so the body's one compare
+    # serves both kinds of layer
+    window = jnp.where(window > 0, window, jnp.iinfo(jnp.int32).max)
     lanes = [(0, 0), (0, 0), (0, W - hd)]
     q = jnp.pad(q, lanes)
     k_new = jnp.pad(jnp.repeat(k_new, G, axis=1), lanes)
     v_new = jnp.pad(jnp.repeat(v_new, G, axis=1), lanes)
 
     kern = functools.partial(
-        _paged_kernel, n_pt=n_pt, ps=ps, G=G, window=window, scale=hd ** -0.5,
+        _paged_kernel, n_pt=n_pt, ps=ps, G=G, scale=hd ** -0.5,
     )
-    row = pl.BlockSpec((1, Hq, W), lambda b, ip, lyr, tbl, qp: (b, 0, 0))
+    row = pl.BlockSpec((1, Hq, W), lambda b, ip, lyr, win, tbl, qp: (b, 0, 0))
     page = pl.BlockSpec(
         (1, 1, ps, Hkv, W),
-        lambda b, ip, lyr, tbl, qp: (jnp.maximum(tbl[b, ip], 0), lyr[0], 0, 0, 0),
+        lambda b, ip, lyr, win, tbl, qp: (jnp.maximum(tbl[b, ip], 0), lyr[0],
+                                          0, 0, 0),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, n_pt),
         in_specs=[row, row, row, page, page],
         out_specs=row,
@@ -250,6 +254,7 @@ def paged_decode_attention_kernel_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, W), q.dtype),
         interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), page_table, q_pos,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      jnp.reshape(window, (1,)).astype(jnp.int32), page_table, q_pos,
       q, k_new, v_new, k_pages, v_pages)
     return out[..., :hd]

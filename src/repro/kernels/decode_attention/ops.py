@@ -50,7 +50,7 @@ def paged_decode_attention(
     page_table: jax.Array,  # [B, n_pt] physical page ids, -1 = unmapped
     q_pos: jax.Array,       # [B] absolute position per row
     *,
-    window: int | None = None,
+    window: int | jax.Array = 0,
     use_kernel: bool | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -61,7 +61,9 @@ def paged_decode_attention(
     existing contiguously.  The pool is read in place, by (page, layer), and
     holds the positions strictly below ``q_pos``; the row's own token
     (``k_new``/``v_new``, not yet written) is attended to as position
-    ``q_pos``, so the caller writes it into the pool after the step.  Two
+    ``q_pos``, so the caller writes it into the pool after the step.
+    ``window`` (0: the whole context) is an int32 operand, not a
+    compile-time constant: a layer scan passes each layer's own.  Two
     paths:
 
     - the pure-jnp gather path (default off-TPU): an explicit
@@ -84,15 +86,16 @@ def paged_decode_attention(
         use_kernel = kernels_enabled()
     if interpret is None:
         interpret = default_interpret()
+    window = jnp.asarray(window, jnp.int32)
     return _paged_decode_attention(q, k_new, v_new, k_pages, v_pages, layer,
-                                   page_table, q_pos, window=window,
+                                   window, page_table, q_pos,
                                    use_kernel=use_kernel, interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("window", "use_kernel", "interpret"))
-def _paged_decode_attention(q, k_new, v_new, k_pages, v_pages, layer,
-                            page_table, q_pos, *, window: int | None,
-                            use_kernel: bool, interpret: bool) -> jax.Array:
+@partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+def _paged_decode_attention(q, k_new, v_new, k_pages, v_pages, layer, window,
+                            page_table, q_pos, *, use_kernel: bool,
+                            interpret: bool) -> jax.Array:
     from .kernel import paged_decode_attention_kernel_call
 
     squeeze = q.ndim == 4
@@ -103,8 +106,8 @@ def _paged_decode_attention(q, k_new, v_new, k_pages, v_pages, layer,
     layer = jnp.asarray(layer, jnp.int32)
     if use_kernel:
         out = paged_decode_attention_kernel_call(
-            q, k_new, v_new, k_pages, v_pages, layer, page_table, q_pos,
-            window=window, interpret=interpret,
+            q, k_new, v_new, k_pages, v_pages, layer, window, page_table,
+            q_pos, interpret=interpret,
         )
         return out[:, None] if squeeze else out
 
@@ -126,9 +129,8 @@ def _paged_decode_attention(q, k_new, v_new, k_pages, v_pages, layer,
     qg = q.reshape(B, Hkv, G, hd) * hd ** -0.5
     s = jnp.einsum("bhgd,bshd->bhgs", qg, kc).astype(jnp.float32)
     qp = q_pos[:, None]
-    keep = (kv_pos >= 0) & (kv_pos <= qp)
-    if window is not None:
-        keep &= kv_pos > qp - window
+    keep = ((kv_pos >= 0) & (kv_pos <= qp)
+            & ((window <= 0) | (kv_pos > qp - window)))
     s = jnp.where(keep[:, None, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgs,bshd->bhgd", p.astype(vc.dtype), vc)

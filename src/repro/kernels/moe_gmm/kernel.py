@@ -1,12 +1,22 @@
-"""Grouped per-expert matmul Pallas kernel (MoE expert FFN).
+"""Grouped matmul Pallas kernel for a dropless expert layer.
 
-Experts are the Graphi "executor groups" of the MoE archs (DESIGN.md §6):
-the leading E axis is embarrassingly parallel (sharded over the mesh's
-expert/model axis at the SPMD level; within a chip it is a parallel grid
-dimension).  Per expert this is a standard MXU-blocked matmul:
+The rows come sorted by group (expert): group ``g`` owns rows
+``offsets[g] .. offsets[g+1]`` and multiplies them by its own matrix
+``w[g]``; rows past ``offsets[G]`` belong to no group and come out zero.
+There is no capacity: a group takes as many rows as were routed to it.
+The matrices may be one layer's of a stack ``[L, G, K, N]``: the layer is
+a scalar-prefetch operand too, and the kernel reads that layer's blocks in
+place, so that a layer scan never slices (and copies) the stack.
 
-grid = (E, C/bc, F/bf, D/bd), D innermost accumulating into f32 VMEM
-scratch.  Defaults bc=bf=bd=256 keep every MXU dim >=128 at ~0.8 MB VMEM.
+The grid walks ``(N / bn, visits)``.  A visit is one (group, row tile)
+pair that holds rows of that group, in order of group and then tile, so
+that the visits of one output tile follow each other and the tile stays
+in VMEM between them, each visit writing only its own group's rows.  The
+visit list is built on the device from the group sizes and reaches the
+kernel by scalar prefetch; its static length bounds the real count (at
+most ``M / bm + G``), and the slots past the real count repeat the last
+visit's block indices, so that they fetch nothing, and compute nothing.
+An empty group has no visit: its matrix is never read.
 """
 from __future__ import annotations
 
@@ -20,52 +30,79 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["moe_gmm_kernel_call"]
 
 
-def _kernel(x_ref, w_ref, o_ref, acc_ref, *, n_d: int):
-    idd = pl.program_id(3)
+def _visits(group_sizes: jax.Array, n_rows: int, bm: int):
+    """The visit list of ``group_sizes`` over ``n_rows`` rows in tiles of
+    ``bm``: (group of each visit, its row tile, the number of real visits,
+    the groups' row offsets [G + 1])."""
+    G = group_sizes.shape[0]
+    sizes = group_sizes.astype(jnp.int32)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+    first = offsets[:-1] // bm
+    n_tiles = jnp.where(sizes > 0, (offsets[1:] - 1) // bm - first + 1, 0)
+    ends = jnp.cumsum(n_tiles)                   # visits up to each group
+    n_visits = ends[-1]
+    v = jnp.minimum(jnp.arange(n_rows // bm + G, dtype=jnp.int32),
+                    jnp.maximum(n_visits - 1, 0))
+    group = jnp.minimum(jnp.searchsorted(ends, v, side="right"), G - 1)
+    tile = first[group] + v - (ends[group] - n_tiles[group])
+    return (group.astype(jnp.int32), tile.astype(jnp.int32),
+            jnp.reshape(n_visits, (1,)).astype(jnp.int32), offsets)
 
-    @pl.when(idd == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[0], w_ref[0],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+def _kernel(layer_ref, group_ref, tile_ref, nv_ref, off_ref, x_ref, w_ref,
+            o_ref, *, bm: int):
+    del layer_ref                        # consumed by the index map
+    v = pl.program_id(1)
 
-    @pl.when(idd == n_d - 1)
-    def _done():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+    @pl.when(v < nv_ref[0])
+    def _visit():
+        g = group_ref[v]
+        rows = tile_ref[v] * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+        mine = (rows >= off_ref[g]) & (rows < off_ref[g + 1])
+        acc = jnp.dot(x_ref[...], w_ref[0, 0], preferred_element_type=jnp.float32)
+        o_ref[...] = jnp.where(mine, acc.astype(o_ref.dtype), o_ref[...])
 
 
 def moe_gmm_kernel_call(
-    x: jax.Array,  # [E, C, D]
-    w: jax.Array,  # [E, D, F]
+    x: jax.Array,            # [M, K] rows sorted by group
+    w: jax.Array,            # [L, G, K, N]
+    layer: jax.Array,        # [] int32: the layer of w read
+    group_sizes: jax.Array,  # [G] int32, summing to at most M
     *,
-    block_c: int,
-    block_f: int,
-    block_d: int,
+    block_m: int,
+    block_n: int,
     interpret: bool,
 ) -> jax.Array:
-    E, C, D = x.shape
-    _, _, F = w.shape
-    bc = min(block_c, C)
-    bf = min(block_f, F)
-    bd = min(block_d, D)
-    if C % bc != 0 or F % bf != 0 or D % bd != 0:
-        raise ValueError(f"block sizes must tile the array: C={C} bc={bc} F={F} bf={bf} D={D} bd={bd}")
-    grid = (E, C // bc, F // bf, D // bd)
+    M, K = x.shape
+    _, G, _, N = w.shape
+    bm = min(block_m, M)
+    bn = min(block_n, N)
+    if M % bm or N % bn:
+        raise ValueError(f"blocks must tile the array: M={M} bm={bm} N={N} bn={bn}")
+    group, tile, n_visits, offsets = _visits(group_sizes, M, bm)
 
-    kern = functools.partial(_kernel, n_d=D // bd)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bc, bd), lambda e, ic, if_, id_: (e, ic, id_)),
-            pl.BlockSpec((1, bd, bf), lambda e, ic, if_, id_: (e, id_, if_)),
-        ],
-        out_specs=pl.BlockSpec((1, bc, bf), lambda e, ic, if_, id_: (e, ic, if_)),
-        out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
+    def x_map(n, v, lyr, grp, til, nv, off):
+        return til[v], 0
+
+    def w_map(n, v, lyr, grp, til, nv, off):
+        return lyr[0], grp[v], 0, n
+
+    def o_map(n, v, lyr, grp, til, nv, off):
+        return til[v], n
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(N // bn, group.shape[0]),
+        in_specs=[pl.BlockSpec((bm, K), x_map),
+                  pl.BlockSpec((1, 1, K, bn), w_map)],
+        out_specs=pl.BlockSpec((bm, bn), o_map),
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, bm=bm),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
-    )(x, w)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), group, tile, n_visits,
+      offsets, x, w)
+    # tiles past the last group's rows are never visited
+    return jnp.where(jnp.arange(M)[:, None] < offsets[-1], out, 0)

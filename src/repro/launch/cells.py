@@ -33,9 +33,10 @@ from repro.train.step import TrainStepConfig, init_train_state, make_train_step
 
 __all__ = ["CellPlan", "Cell", "plan_for", "build_cell", "cell_matrix", "skip_reason"]
 
-# archs whose every block attends over the full context: long_500k (524k
+# archs with blocks that attend over the full context: long_500k (524k
 # decode) is quadratic-cost / unbounded-cache for them -> skipped, per the
-# assignment ("skip for pure full-attention archs").
+# assignment ("skip for pure full-attention archs"); mellum2's every fourth
+# layer is full, so its stacked cache would hold 524k positions per layer
 FULL_ATTENTION_ARCHS = {
     "gemma-2b",
     "yi-9b",
@@ -44,6 +45,7 @@ FULL_ATTENTION_ARCHS = {
     "olmoe-1b-7b",
     "granite-moe-1b-a400m",
     "whisper-medium",
+    "mellum2-12b-a2-5b",
 }
 
 
@@ -236,7 +238,7 @@ def build_cell(
 
 
 def cell_matrix(archs: list[str] | None = None) -> list[tuple[str, str]]:
-    """The full 40-cell (arch x shape) matrix, including skipped cells."""
+    """The full (arch x shape) matrix, including skipped cells."""
     from repro.configs.base import list_archs
 
     archs = archs or list_archs()

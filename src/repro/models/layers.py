@@ -15,6 +15,9 @@ import jax.numpy as jnp
 __all__ = [
     "rms_norm",
     "apply_rope",
+    "rope_inv_freq",
+    "yarn_inv_freq",
+    "yarn_scale",
     "sinusoidal_positions",
     "glu_ffn",
     "chunked_attention",
@@ -27,6 +30,14 @@ __all__ = [
 _NEG_INF = -1e30
 
 
+def _in_window(kv_pos: jax.Array, q_pos: jax.Array, window) -> jax.Array:
+    """Keep-mask of a sliding window: entries among the last ``window``
+    positions up to the query's; a window of 0 keeps every entry.  The
+    window is an int or an int32 scalar that may be traced (a layer's
+    window read inside the layer scan)."""
+    return (window <= 0) | (kv_pos > q_pos - window)
+
+
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     dt = x.dtype
     x = x.astype(jnp.float32)
@@ -34,16 +45,55 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     return ((x * jax.lax.rsqrt(var + eps)) * (1.0 + scale.astype(jnp.float32))).astype(dt)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: [..., S, H, hd]; positions: [..., S]."""
-    if theta <= 0:
-        return x
-    hd = x.shape[-1]
+def rope_inv_freq(hd: int, theta: float) -> jax.Array:
+    """Rotary inverse frequencies ``theta ** (-2i / hd)``, ``i < hd/2``."""
     half = hd // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, half]
+    return jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+
+
+def yarn_inv_freq(hd: int, theta: float, factor: float, original: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0) -> jax.Array:
+    """YaRN's inverse frequencies (HF ``_compute_yarn_parameters``, with
+    ``truncate``): dimensions that turn fewer than ``beta_slow`` times over
+    the ``original`` context are interpolated by ``factor``, those that turn
+    more than ``beta_fast`` times are kept, and a linear ramp blends the
+    ones between."""
+    def correction_dim(rotations):
+        return hd * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), hd - 1)
+    if low == high:
+        high += 0.001
+    extrap = rope_inv_freq(hd, theta)
+    ramp = jnp.clip((jnp.arange(hd // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return extrap / factor * ramp + extrap * (1 - ramp)
+
+
+def yarn_scale(factor: float) -> float:
+    """YaRN's attention factor, ``0.1 ln(factor) + 1``, which multiplies cos
+    and sin (so q.k by its square)."""
+    return 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 0.0, *,
+               inv_freq: jax.Array | None = None,
+               scale: jax.Array | float | None = None) -> jax.Array:
+    """Rotary embedding. x: [..., S, H, hd]; positions: [..., S].
+
+    The frequencies are ``inv_freq`` ([hd/2], may be traced) or, when it is
+    not given, those of ``theta`` (no rotation for ``theta <= 0``);
+    ``scale`` multiplies cos and sin (YaRN's attention factor)."""
+    if inv_freq is None:
+        if theta <= 0:
+            return x
+        inv_freq = rope_inv_freq(x.shape[-1], theta)
+    half = x.shape[-1] // 2
+    ang = positions[..., :, None].astype(jnp.float32) * inv_freq  # [..., S, half]
     cos = jnp.cos(ang)[..., :, None, :]  # broadcast over heads
     sin = jnp.sin(ang)[..., :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -86,15 +136,14 @@ def _mask_chunk(
     kv_pos: jax.Array,  # [Ck]
     *,
     causal: bool,
-    window: int | None,
+    window: int | jax.Array,
     kv_len: jax.Array | None,
 ) -> jax.Array:
     """Boolean keep-mask [Sq, Ck]."""
     m = jnp.ones((q_pos.shape[0], kv_pos.shape[0]), bool)
     if causal:
         m &= kv_pos[None, :] <= q_pos[:, None]
-    if window is not None:
-        m &= kv_pos[None, :] > q_pos[:, None] - window
+    m &= _in_window(kv_pos[None, :], q_pos[:, None], window)
     if kv_len is not None:
         m &= kv_pos[None, :] < kv_len
     return m
@@ -106,7 +155,7 @@ def chunked_attention(
     v: jax.Array,  # [B, Skv, Hkv, hd]
     *,
     causal: bool = True,
-    window: int | None = None,
+    window: int | jax.Array = 0,
     q_offset: int = 0,
     kv_len: jax.Array | None = None,
     chunk: int = 2048,
@@ -183,7 +232,7 @@ def decode_attention(
     kv_pos: jax.Array,   # [Smax] | [B, Smax] absolute position per slot; -1 = empty
     q_pos: jax.Array,    # [] | [B] absolute position of the query token
     *,
-    window: int | None = None,
+    window: int | jax.Array = 0,
 ) -> jax.Array:
     """Single-token attention against a KV cache (memory-bound path).
 
@@ -200,14 +249,11 @@ def decode_attention(
     s = jnp.einsum("bhgd,bshd->bhgs", qg, k_cache).astype(jnp.float32)
     if kv_pos.ndim == 2:
         qp = q_pos[:, None]
-        keep = (kv_pos >= 0) & (kv_pos <= qp)
-        if window is not None:
-            keep &= kv_pos > qp - window
+        keep = (kv_pos >= 0) & (kv_pos <= qp) & _in_window(kv_pos, qp, window)
         keep = keep[:, None, None, :]
     else:
-        keep = (kv_pos >= 0) & (kv_pos <= q_pos)
-        if window is not None:
-            keep &= kv_pos > q_pos - window
+        keep = ((kv_pos >= 0) & (kv_pos <= q_pos)
+                & _in_window(kv_pos, q_pos, window))
         keep = keep[None, None, None, :]
     s = jnp.where(keep, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
@@ -222,7 +268,7 @@ def masked_attention(
     kv_pos: jax.Array,   # [Skv] | [B, Skv] absolute position per entry; -1 = empty
     q_pos: jax.Array,    # [Sq]  | [B, Sq] absolute position per query row
     *,
-    window: int | None = None,
+    window: int | jax.Array = 0,
 ) -> jax.Array:
     """Position-table-masked GQA attention for ``Sq >= 1`` query rows.
 
@@ -240,9 +286,8 @@ def masked_attention(
     s = jnp.einsum("bqhgd,bshd->bhgqs", qg, k).astype(jnp.float32)
     kvp = kv_pos if kv_pos.ndim == 2 else kv_pos[None]    # [B|1, Skv]
     qp = q_pos if q_pos.ndim == 2 else q_pos[None]        # [B|1, Sq]
-    keep = (kvp[:, None, :] >= 0) & (kvp[:, None, :] <= qp[:, :, None])
-    if window is not None:
-        keep &= kvp[:, None, :] > qp[:, :, None] - window
+    keep = ((kvp[:, None, :] >= 0) & (kvp[:, None, :] <= qp[:, :, None])
+            & _in_window(kvp[:, None, :], qp[:, :, None], window))
     s = jnp.where(keep[:, None, None, :, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqs,bshd->bqhgd", p.astype(v.dtype), v)

@@ -1,5 +1,15 @@
-"""Mixture-of-Experts FFN: grouped top-k routing with capacity-based
-gather dispatch.
+"""Mixture-of-Experts FFN: two layers.
+
+:func:`expert_layer` (serving) is dropless and holds a share of the
+experts: it routes every token over all of the router's experts, keeps the
+(token, expert) pairs that fall on the experts it holds, and computes them
+with a grouped matmul over the pairs sorted by expert
+(``kernels/moe_gmm``), however many a batch sends to one expert; so a
+served token's result never depends on its batch.  The experts it does not
+hold add nothing: on a chip of an expert-parallel deployment their share
+comes from the other chips.
+
+:func:`moe_ffn` (sharded training) routes with per-group capacity:
 
 **Grouped routing** (t5x/GShard ``num_groups`` style): tokens are split into
 G groups (G = the mesh's data-parallel extent, so each group is resident on
@@ -22,15 +32,19 @@ import jax.numpy as jnp
 
 from repro.dist.sharding import mesh_context, shard
 
-__all__ = ["moe_ffn", "init_moe_params"]
+__all__ = ["moe_ffn", "expert_layer", "init_moe_params"]
 
 
-def init_moe_params(key, d_model: int, d_ff: int, n_experts: int, dtype):
+def init_moe_params(key, d_model: int, d_ff: int, n_experts: int, dtype,
+                    router_experts: int | None = None):
+    """Router over ``router_experts`` (default: ``n_experts``, all held),
+    float32, and the ``n_experts`` experts held."""
     k1, k2, k3, k4 = jax.random.split(key, 4)
     s_in = d_model ** -0.5
     s_out = d_ff ** -0.5
+    R = router_experts or n_experts
     return {
-        "router": (jax.random.normal(k1, (d_model, n_experts)) * s_in).astype(jnp.float32),
+        "router": (jax.random.normal(k1, (d_model, R)) * s_in).astype(jnp.float32),
         "w_gate": (jax.random.normal(k2, (n_experts, d_model, d_ff)) * s_in).astype(dtype),
         "w_up": (jax.random.normal(k3, (n_experts, d_model, d_ff)) * s_in).astype(dtype),
         "w_down": (jax.random.normal(k4, (n_experts, d_ff, d_model)) * s_out).astype(dtype),
@@ -60,6 +74,9 @@ def moe_ffn(
     B, S, D = x.shape
     T = B * S
     E = params["router"].shape[-1]
+    if params["w_gate"].shape[0] != E:
+        raise ValueError("capacity routing needs every routed expert held "
+                         f"({params['w_gate'].shape[0]} held of {E})")
     G = n_groups or _infer_groups(T)
     Tg = T // G
     # per-group slots per expert; multiple of 8 keeps lanes aligned
@@ -133,3 +150,55 @@ def moe_ffn(
         out = out + w[..., None] * y_r.astype(jnp.float32)
 
     return out.reshape(B, S, D).astype(x.dtype), aux_loss
+
+
+def expert_layer(
+    params,
+    x: jax.Array,                     # [B, S, D]
+    *,
+    top_k: int,
+    first_expert: int = 0,
+    act: str = "silu",
+    token_mask: jax.Array | None = None,   # [B, S]; False: idle or padding
+    layer: jax.Array | None = None,
+):
+    """Dropless top-k experts over the ones held (module docstring).
+
+    The router (float32, ``[D, R]``) scores all ``R`` experts; softmax, the
+    top ``top_k``, renormalised over them.  The held experts are
+    ``first_expert .. first_expert + E - 1``, ``E`` the experts' leading
+    axis; with ``layer`` given, the experts' matrices are every layer's,
+    ``[L, E, ...]``, and the grouped matmul reads that layer's in place.
+    Tokens masked out route nowhere.  Returns (out [B, S, D], int32
+    [2]: the routed pairs that fell on held experts, and the held experts
+    that got at least one)."""
+    from repro.kernels.moe_gmm import moe_gmm
+
+    B, S, D = x.shape
+    T = B * S
+    E = params["w_gate"].shape[0 if layer is None else 1]
+    with jax.named_scope("repro.moe"):
+        xt = x.reshape(T, D)
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), params["router"],
+                            precision=jax.lax.Precision.HIGHEST)
+        gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        gate = gate / gate.sum(axis=-1, keepdims=True)
+        local = idx - first_expert
+        held = (local >= 0) & (local < E)
+        if token_mask is not None:
+            held &= token_mask.reshape(T, 1)
+        # pairs sorted by held expert; the others go last, past every group
+        key = jnp.where(held, local, E).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
+        tok = order // top_k
+        rows = xt[tok]
+        h = moe_gmm(rows, params["w_gate"], sizes, layer=layer)
+        h = jax.nn.silu(h) if act == "silu" else jax.nn.gelu(h)
+        h = h * moe_gmm(rows, params["w_up"], sizes, layer=layer)
+        y = moe_gmm(h, params["w_down"], sizes, layer=layer)
+        w = jnp.where(held, gate, 0.0).reshape(-1)[order]
+        out = jnp.zeros((T, D), jnp.float32).at[tok].add(
+            y.astype(jnp.float32) * w[:, None])
+        counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+    return out.reshape(B, S, D).astype(x.dtype), counts

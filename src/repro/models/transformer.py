@@ -12,7 +12,14 @@ Functional API (pure fns over a params pytree):
 Layers are stacked + ``lax.scan``-swept when the block pattern is homogeneous
 (``cfg.scan_layers``), which keeps compile time flat in depth — essential for
 the 40-cell dry-run sweep.  Heterogeneous archs (recurrentgemma) use a python
-loop over per-layer param dicts.
+loop over per-layer param dicts.  What else differs between layers — the
+attention window and the rope tables (``_layer_ops``) — is an operand of
+the scan beside the weights, so windowed and full layers, plain and YaRN
+rope, share one compiled layer.
+
+Expert layers: training runs ``moe.moe_ffn`` (capacity routing, every
+expert held); every serving path runs ``moe.expert_layer`` (dropless, the
+experts held of those routed) and returns its counts.
 """
 from __future__ import annotations
 
@@ -35,10 +42,13 @@ from .layers import (
     glu_ffn,
     masked_attention,
     rms_norm,
+    rope_inv_freq,
     sinusoidal_positions,
+    yarn_inv_freq,
+    yarn_scale,
 )
 from .mamba import init_mamba_cache, init_mamba_params, mamba_block, mamba_decode_step
-from .moe import init_moe_params, moe_ffn
+from .moe import expert_layer, init_moe_params, moe_ffn
 
 __all__ = [
     "init_params",
@@ -79,7 +89,8 @@ def _init_attn(key, cfg: ModelConfig, dtype):
 
 def _init_mlp(key, cfg: ModelConfig, dtype):
     if cfg.n_experts:
-        return init_moe_params(key, cfg.d_model, cfg.d_ff, cfg.n_experts, dtype)
+        return init_moe_params(key, cfg.d_model, cfg.d_ff, cfg.n_experts, dtype,
+                               cfg.routed_experts)
     d, f = cfg.d_model, cfg.d_ff
     k1, k2, k3 = jax.random.split(key, 3)
     return {
@@ -149,8 +160,11 @@ def init_params(cfg: ModelConfig, key) -> dict:
 # blocks
 # ---------------------------------------------------------------------------
 
-def _attn_apply(cfg: ModelConfig, ap, x, *, positions, causal, window, kv_override=None):
+def _attn_apply(cfg: ModelConfig, ap, x, *, positions, causal, lo=None,
+                kv_override=None):
     """Full-sequence attention. kv_override: (k_src, kv_positions) for cross.
+    ``lo``: the layer's window and rope (``_layer_ops``); None for the
+    encoder and cross-attention: no window, the config's plain rope.
 
     Megatron layout: inside attention the *head* dim carries the model axis
     (seq gathered); the residual stream outside is seq-sharded.  Explicit
@@ -185,11 +199,14 @@ def _attn_apply(cfg: ModelConfig, ap, x, *, positions, causal, window, kv_overri
         if tp > 1:
             q_chunk = 0   # q rows sharded: no q loop (a lax.map would
             #               serialize one device-resident chunk at a time)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    if kv_override is None:
-        k = apply_rope(k, positions, cfg.rope_theta)
+    if lo is None:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        if kv_override is None:
+            k = apply_rope(k, positions, cfg.rope_theta)
+    else:
+        q, k = _rope(q, positions, lo), _rope(k, positions, lo)
     out = chunked_attention(
-        q, k, v, causal=causal, window=window,
+        q, k, v, causal=causal, window=0 if lo is None else lo["window"],
         chunk=cfg.attn_chunk, q_chunk=q_chunk,
     )
     if head_parallel:
@@ -204,20 +221,68 @@ def _attn_apply(cfg: ModelConfig, ap, x, *, positions, causal, window, kv_overri
 
 
 def _mlp_apply(cfg: ModelConfig, mp, x):
-    """Returns (out, aux)."""
+    """Training's feed-forward. Returns (out, aux)."""
     if cfg.n_experts:
         return moe_ffn(mp, x, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor, act=cfg.act)
     return glu_ffn(x, mp["w_gate"], mp["w_up"], mp["w_down"], cfg.act), 0.0
 
 
-def _block_train(cfg: ModelConfig, lp, kind: str, x, *, positions, window, enc=None, causal=True):
+def _mlp_serve(cfg: ModelConfig, mp, x, token_mask=None, layer=None):
+    """Serving's feed-forward: the dropless expert layer over the experts
+    held, or the dense one.  Returns (out, int32 [2] expert counts:
+    routed pairs on held experts, held experts hit; None when dense).
+    With ``layer``, ``mp``'s expert matrices are every layer's, read in
+    place."""
+    if cfg.n_experts:
+        return expert_layer(mp, x, top_k=cfg.top_k,
+                            first_expert=cfg.first_expert, act=cfg.act,
+                            token_mask=token_mask, layer=layer)
+    return glu_ffn(x, mp["w_gate"], mp["w_up"], mp["w_down"], cfg.act), None
+
+
+def _layer_ops(cfg: ModelConfig) -> dict:
+    """What differs between layers beside their weights, stacked ``[L, ...]``
+    as operands of the layer scan: each layer's attention window (0: the
+    whole context), and, for rope archs, its inverse frequencies
+    ``[hd/2]`` and the scale of its cos and sin (YaRN's attention factor;
+    1 for plain rope)."""
+    ops = {"window": jnp.asarray(cfg.layer_windows(), jnp.int32)}
+    if cfg.rope_theta > 0:
+        hd = cfg.resolved_head_dim
+        yarn = np.asarray(cfg.layer_yarn(), np.int32)
+        tables = [rope_inv_freq(hd, cfg.rope_theta)]
+        scales = [1.0]
+        if yarn.any():
+            tables.append(yarn_inv_freq(hd, cfg.rope_theta, cfg.yarn_factor,
+                                        cfg.yarn_original_max_position))
+            scales.append(yarn_scale(cfg.yarn_factor))
+        ops["inv_freq"] = jnp.stack(tables)[yarn]
+        ops["rope_scale"] = jnp.asarray(scales, jnp.float32)[yarn]
+    return ops
+
+
+def _layer_op(ops: dict, i: int) -> dict:
+    """Layer ``i``'s operands (the unscanned layer loop)."""
+    return {k: v[i] for k, v in ops.items()}
+
+
+def _rope(x, positions, lo):
+    """The layer's rope (``lo`` from ``_layer_ops``); none without tables."""
+    if "inv_freq" not in lo:
+        return x
+    return apply_rope(x, positions, inv_freq=lo["inv_freq"], scale=lo["rope_scale"])
+
+
+def _block_train(cfg: ModelConfig, lp, kind: str, x, *, positions, lo=None,
+                 enc=None, causal=True):
     """One residual block, full-sequence (train/prefill). Returns (x, aux)."""
     aux = 0.0
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if kind == "ssm":
         return x + mamba_block(lp["ssm"], h), aux
     if kind == "attn":
-        mix, _ = _attn_apply(cfg, lp["attn"], h, positions=positions, causal=causal, window=window)
+        mix, _ = _attn_apply(cfg, lp["attn"], h, positions=positions,
+                             causal=causal, lo=lo)
     else:  # rglru
         mix = rglru_block(lp["rnn"], h)
     if cfg.parallel_block:
@@ -230,7 +295,7 @@ def _block_train(cfg: ModelConfig, lp, kind: str, x, *, positions, window, enc=N
             xo, _ = _attn_apply(
                 cfg, lp["xattn"], hx,
                 positions=jnp.arange(hx.shape[1]),
-                causal=False, window=None, kv_override=(enc, None),
+                causal=False, kv_override=(enc, None),
             )
             x = x + xo
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
@@ -271,17 +336,13 @@ def _logits(cfg: ModelConfig, params, x):
     return jnp.einsum("...d,dv->...v", x, w).astype(jnp.float32)
 
 
-def _window_for(cfg: ModelConfig, kind: str) -> int | None:
-    return cfg.sliding_window if (kind == "attn" and cfg.sliding_window) else None
-
-
 def _encode(cfg: ModelConfig, params, frames):
     """Whisper encoder over precomputed frame embeddings (stub frontend)."""
     x = frames + sinusoidal_positions(frames.shape[1], cfg.d_model, frames.dtype)[None]
     pos = jnp.arange(frames.shape[1])
 
     def f(x, lp):
-        x, _ = _block_train(cfg, lp, "attn", x, positions=pos, window=None, causal=False)
+        x, _ = _block_train(cfg, lp, "attn", x, positions=pos, causal=False)
         return x, None
 
     x, _ = jax.lax.scan(f, x, params["enc_layers"])
@@ -312,24 +373,27 @@ def forward(cfg: ModelConfig, params, batch: dict, *, remat: bool = False):
     # carries — see DESIGN.md §9). Dropped automatically when S % tp != 0.
     x = shard(x, "batch", "seq", None)
 
+    ops = _layer_ops(cfg)
     if cfg.scan_layers and cfg.is_homogeneous:
         kind = kinds[0]
-        window = _window_for(cfg, kind)
 
-        def body(carry, lp):
+        def body(carry, inp):
             x, aux = carry
-            x, a = _block_train(cfg, lp, kind, x, positions=positions, window=window, enc=enc)
+            lp, lo = inp
+            x, a = _block_train(cfg, lp, kind, x, positions=positions,
+                                lo=lo, enc=enc)
             return (shard(x, "batch", "seq", None), aux + a), None
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                   (params["layers"], ops))
     else:
         aux = 0.0
-        for lp, kind in zip(params["layers"], kinds):
+        for i, (lp, kind) in enumerate(zip(params["layers"], kinds)):
             blk = partial(
                 _block_train, cfg, lp, kind,
-                positions=positions, window=_window_for(cfg, kind), enc=enc,
+                positions=positions, lo=_layer_op(ops, i), enc=enc,
             )
             if remat:
                 blk = jax.checkpoint(blk, prevent_cse=False)
@@ -346,8 +410,10 @@ def forward(cfg: ModelConfig, params, batch: dict, *, remat: bool = False):
 # ---------------------------------------------------------------------------
 
 def _attn_cache_len(cfg: ModelConfig, max_len: int) -> int:
-    w = cfg.sliding_window
-    return min(max_len, w) if w else max_len
+    """Slots of an attention layer's (ring) cache: the widest window, or
+    ``max_len`` where some layer attends over the whole context."""
+    windows = cfg.layer_windows()
+    return max_len if 0 in windows else min(max_len, max(windows))
 
 
 def _layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int, dtype,
@@ -457,7 +523,7 @@ def cache_evict_slot(cfg: ModelConfig, cache: dict, slot) -> dict:
     return cache
 
 
-def _block_decode(cfg: ModelConfig, lp, kind: str, x, lc, *, q_pos, enc=None):
+def _block_decode(cfg: ModelConfig, lp, kind: str, x, lc, *, q_pos, lo, enc=None):
     """Single-token block step. x: [B,1,D]. Returns (x, new layer cache)."""
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if kind == "ssm":
@@ -473,8 +539,7 @@ def _block_decode(cfg: ModelConfig, lp, kind: str, x, lc, *, q_pos, enc=None):
         k = jnp.einsum("bsd,de->bse", h, ap["wk"]).reshape(B, 1, cfg.n_kv_heads, hd)
         v = jnp.einsum("bsd,de->bse", h, ap["wv"]).reshape(B, 1, cfg.n_kv_heads, hd)
         pos_arr = q_pos[:, None] if q_pos.ndim else q_pos[None]
-        q = apply_rope(q, pos_arr, cfg.rope_theta)
-        k = apply_rope(k, pos_arr, cfg.rope_theta)
+        q, k = _rope(q, pos_arr, lo), _rope(k, pos_arr, lo)
         C = lc["k"].shape[1]
         lc = dict(lc)
         if q_pos.ndim:
@@ -490,22 +555,22 @@ def _block_decode(cfg: ModelConfig, lp, kind: str, x, lc, *, q_pos, enc=None):
             lc["v"] = jax.lax.dynamic_update_index_in_dim(lc["v"], v[:, 0], slot, 1)
             lc["pos"] = jax.lax.dynamic_update_index_in_dim(lc["pos"], q_pos, slot, 0)
         out = decode_attention(
-            q, lc["k"], lc["v"], lc["pos"], q_pos, window=_window_for(cfg, kind)
+            q, lc["k"], lc["v"], lc["pos"], q_pos, window=lo["window"]
         )
         mix = jnp.einsum("bse,ed->bsd", out.reshape(B, 1, -1), ap["wo"])
     if cfg.parallel_block:
-        mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h)
+        mlp_out, _ = _mlp_serve(cfg, lp["mlp"], h)
         return x + mix + mlp_out, lc
     x = x + mix
     if enc is not None:
         hx = rms_norm(x, lp["ln_x"], cfg.norm_eps)
         xo, _ = _attn_apply(
             cfg, lp["xattn"], hx, positions=q_pos[None, None],
-            causal=False, window=None, kv_override=(enc, None),
+            causal=False, kv_override=(enc, None),
         )
         x = x + xo
     h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h2)
+    mlp_out, _ = _mlp_serve(cfg, lp["mlp"], h2)
     return x + mlp_out, lc
 
 
@@ -537,8 +602,11 @@ def prefill(cfg: ModelConfig, params, batch: dict, cache: dict):
     positions = jnp.arange(S)
     kinds = cfg.layer_kinds()
     x = shard(x, "batch", "seq", None)
+    # padding routes to no expert
+    real = (None if valid_len is None else
+            jnp.broadcast_to(positions < valid_len, x.shape[:2]))
 
-    def run_block(x, lp, lc, kind):
+    def run_block(x, lp, lc, kind, lo):
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         if kind == "ssm":
             # full-seq scan, then regenerate the decode state via step-free
@@ -551,38 +619,38 @@ def prefill(cfg: ModelConfig, params, batch: dict, cache: dict):
             mix = out
         else:
             mix, (k, v) = _attn_apply(
-                cfg, lp["attn"], h, positions=positions,
-                causal=True, window=_window_for(cfg, kind),
+                cfg, lp["attn"], h, positions=positions, causal=True, lo=lo,
             )
             lc = _write_prefill(lc, k, v)
         if cfg.parallel_block:
-            mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h)
+            mlp_out, _ = _mlp_serve(cfg, lp["mlp"], h, real)
             return x + mix + mlp_out, lc
         x = x + mix
         if enc is not None:
             hx = rms_norm(x, lp["ln_x"], cfg.norm_eps)
             xo, _ = _attn_apply(
                 cfg, lp["xattn"], hx, positions=positions,
-                causal=False, window=None, kv_override=(enc, None),
+                causal=False, kv_override=(enc, None),
             )
             x = x + xo
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h2)
+        mlp_out, _ = _mlp_serve(cfg, lp["mlp"], h2, real)
         return shard(x + mlp_out, "batch", "seq", None), lc
 
+    ops = _layer_ops(cfg)
     if cfg.scan_layers and cfg.is_homogeneous:
         kind = kinds[0]
 
         def body(x, inp):
-            lp, lc = inp
-            x, lc = run_block(x, lp, lc, kind)
+            lp, lc, lo = inp
+            x, lc = run_block(x, lp, lc, kind, lo)
             return x, lc
 
-        x, new_layers = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
+        x, new_layers = jax.lax.scan(body, x, (params["layers"], cache["layers"], ops))
     else:
         new_layers = []
-        for lp, lc, kind in zip(params["layers"], cache["layers"], kinds):
-            x, lc = run_block(x, lp, lc, kind)
+        for i, (lp, lc, kind) in enumerate(zip(params["layers"], cache["layers"], kinds)):
+            x, lc = run_block(x, lp, lc, kind, _layer_op(ops, i))
             new_layers.append(lc)
 
     if valid_len is not None:
@@ -656,19 +724,21 @@ def decode_step(cfg: ModelConfig, params, tokens, cache: dict):
     enc = cache.get("enc")
     kinds = cfg.layer_kinds()
 
+    ops = _layer_ops(cfg)
     if cfg.scan_layers and cfg.is_homogeneous:
         kind = kinds[0]
 
         def body(x, inp):
-            lp, lc = inp
-            x, lc = _block_decode(cfg, lp, kind, x, lc, q_pos=q_pos, enc=enc)
+            lp, lc, lo = inp
+            x, lc = _block_decode(cfg, lp, kind, x, lc, q_pos=q_pos, lo=lo, enc=enc)
             return x, lc
 
-        x, new_layers = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
+        x, new_layers = jax.lax.scan(body, x, (params["layers"], cache["layers"], ops))
     else:
         new_layers = []
-        for lp, lc, kind in zip(params["layers"], cache["layers"], kinds):
-            x, lc = _block_decode(cfg, lp, kind, x, lc, q_pos=q_pos, enc=enc)
+        for i, (lp, lc, kind) in enumerate(zip(params["layers"], cache["layers"], kinds)):
+            x, lc = _block_decode(cfg, lp, kind, x, lc, q_pos=q_pos,
+                                  lo=_layer_op(ops, i), enc=enc)
             new_layers.append(lc)
 
     cache = dict(cache)
@@ -764,33 +834,65 @@ def free_pages(cache: dict, slot: int) -> tuple[dict, list[int]]:
     return {**cache, "table": table, "len": length}, freed
 
 
-def _paged_layers(cfg: ModelConfig, params, x, block):
-    """Run ``block(x, lp, layer) -> (x, (k, v))`` over every layer, scanned
-    when the arch stacks its layers; returns x and the stacked [L, ...] K/V.
-    The pool is closed over, never a scan operand: each layer reads its
-    pages in place through ``layer``."""
-    if _paged_stacked(cfg):
-        def body(x, inp):
-            lp, layer = inp
-            return block(x, lp, layer)
+_EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
 
-        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-        return jax.lax.scan(body, x, (params["layers"], layers))
-    ks, vs = [], []
-    for layer, lp in enumerate(params["layers"]):
-        x, (k, v) = block(x, lp, jnp.int32(layer))
-        ks.append(k)
-        vs.append(v)
-    return x, (jnp.stack(ks), jnp.stack(vs))
+
+def _paged_layers(cfg: ModelConfig, params, x, block):
+    """Run ``block(x, lp, layer, lo, stacked) -> (x, (k, v, expert counts))``
+    over every layer (``lo``: its ``_layer_ops``), scanned when the arch
+    stacks its layers; returns x, the stacked [L, ...] K/V and the counts
+    summed over the layers (zeros for a dense arch, whose blocks count
+    nothing).  The pool is closed over, never a scan operand:
+    each layer reads its pages in place through ``layer``.  So are a
+    scanned expert arch's expert matrices (``stacked``, ``lp["mlp"]`` holds
+    only the router): a scan operand's slice would copy a layer's experts
+    (hundreds of MB) every layer of every step."""
+    ops = _layer_ops(cfg)
+    if _paged_stacked(cfg):
+        layers = params["layers"]
+        stacked = None
+        if cfg.n_experts:
+            stacked = {k: layers["mlp"][k] for k in _EXPERT_MATRICES}
+            rest = {k: v for k, v in layers["mlp"].items() if k not in stacked}
+            layers = {**layers, "mlp": rest}
+
+        def body(x, inp):
+            lp, layer, lo = inp
+            return block(x, lp, layer, lo, stacked)
+
+        idx = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        x, (k, v, counts) = jax.lax.scan(body, x, (layers, idx, ops))
+    else:
+        outs = []
+        for layer, lp in enumerate(params["layers"]):
+            x, out = block(x, lp, jnp.int32(layer), _layer_op(ops, layer), None)
+            outs.append(out)
+        k, v, counts = zip(*outs)
+        k, v = jnp.stack(k), jnp.stack(v)
+        counts = None if counts[0] is None else jnp.stack(counts)
+    if counts is None:
+        return x, (k, v), jnp.zeros((2,), jnp.int32)
+    return x, (k, v), counts.sum(axis=0)
+
+
+def _paged_mlp(lp, layer, stacked):
+    """A paged block's feed-forward weights, and the layer to read of them:
+    the scan's slice, or the router's slice beside every layer's experts."""
+    if stacked is None:
+        return lp["mlp"], None
+    return {**lp["mlp"], **stacked}, layer
 
 
 def paged_decode_step(cfg: ModelConfig, params, tokens, cache: dict):
     """One decode step over the paged cache.  tokens: [B, 1].
 
     Reads the pool (positions below each row's length) and writes nothing:
-    returns (logits [B, Vp], {"k", "v"}: this step's K/V [L, B, Hkv, hd]),
+    returns (logits [B, Vp], {"k", "v"}: this step's K/V [L, B, Hkv, hd],
     which the engine installs at each row's length with
-    :func:`paged_install_decode`."""
+    :func:`paged_install_decode`; "experts": int32 [2], the routed pairs
+    that fell on held experts and the held experts hit, summed over the
+    layers).  A row whose page table maps nothing is idle: it routes to no
+    expert."""
     from repro.kernels.decode_attention import paged_decode_attention
 
     q_pos = jnp.asarray(cache["len"], jnp.int32)
@@ -800,31 +902,32 @@ def paged_decode_step(cfg: ModelConfig, params, tokens, cache: dict):
     B = x.shape[0]
     hd = cfg.resolved_head_dim
     pos_arr = q_pos[:, None]
+    active = (table[:, 0] >= 0)[:, None]
 
-    def block(x, lp, layer):
+    def block(x, lp, layer, lo, stacked):
         ap = lp["attn"]
+        mp, at = _paged_mlp(lp, layer, stacked)
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         q = jnp.einsum("bsd,de->bse", h, ap["wq"]).reshape(B, 1, cfg.n_heads, hd)
         k = jnp.einsum("bsd,de->bse", h, ap["wk"]).reshape(B, 1, cfg.n_kv_heads, hd)
         v = jnp.einsum("bsd,de->bse", h, ap["wv"]).reshape(B, 1, cfg.n_kv_heads, hd)
-        q = apply_rope(q, pos_arr, cfg.rope_theta)
-        k = apply_rope(k, pos_arr, cfg.rope_theta)[:, 0]
+        q = _rope(q, pos_arr, lo)
+        k = _rope(k, pos_arr, lo)[:, 0]
         v = v[:, 0]
         out = paged_decode_attention(q, k, v, pages["k"], pages["v"], layer,
-                                     table, q_pos,
-                                     window=_window_for(cfg, "attn"))
+                                     table, q_pos, window=lo["window"])
         mix = jnp.einsum("bse,ed->bsd", out.reshape(B, 1, -1), ap["wo"])
         if cfg.parallel_block:
-            mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h)
-            return x + mix + mlp_out, (k, v)
+            mlp_out, counts = _mlp_serve(cfg, mp, h, active, at)
+            return x + mix + mlp_out, (k, v, counts)
         x = x + mix
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h2)
-        return x + mlp_out, (k, v)
+        mlp_out, counts = _mlp_serve(cfg, mp, h2, active, at)
+        return x + mlp_out, (k, v, counts)
 
-    x, (k_new, v_new) = _paged_layers(cfg, params, x, block)
+    x, (k_new, v_new), counts = _paged_layers(cfg, params, x, block)
     x = rms_norm(x[:, -1], params["final_norm"], cfg.norm_eps)
-    return _logits(cfg, params, x), {"k": k_new, "v": v_new}
+    return _logits(cfg, params, x), {"k": k_new, "v": v_new, "experts": counts}
 
 
 def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
@@ -841,7 +944,8 @@ def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
     writes and lets it run concurrently with the decode step.
 
     Returns (logits [1, Vp] at position start+valid_len-1,
-    k_chunk, v_chunk — [L, T, Hkv, hd]).
+    k_chunk, v_chunk — [L, T, Hkv, hd] — and the expert counts, int32 [2],
+    as :func:`paged_decode_step`'s; padding routes to no expert).
     """
     T = tokens.shape[1]
     start = jnp.asarray(start, jnp.int32)
@@ -850,7 +954,7 @@ def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
     pos = start + jnp.arange(T, dtype=jnp.int32)          # [T]
     x = _embed(cfg, params, tokens)                        # rope: positionless
     hd = cfg.resolved_head_dim
-    window = _window_for(cfg, "attn")
+    real = (jnp.arange(T) < valid_len)[None]
     n_pt = table_row.shape[0]
     ps = page_size
     rows = jnp.maximum(table_row, 0)
@@ -859,14 +963,15 @@ def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
     ctx_pos = jnp.where(mapped & (idx < start), idx, -1)
     kv_pos = jnp.concatenate([ctx_pos, pos])
 
-    def block(x, lp, layer):
+    def block(x, lp, layer, lo, stacked):
         ap = lp["attn"]
+        mp, at = _paged_mlp(lp, layer, stacked)
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         q = jnp.einsum("bsd,de->bse", h, ap["wq"]).reshape(1, T, cfg.n_heads, hd)
         k = jnp.einsum("bsd,de->bse", h, ap["wk"]).reshape(1, T, cfg.n_kv_heads, hd)
         v = jnp.einsum("bsd,de->bse", h, ap["wv"]).reshape(1, T, cfg.n_kv_heads, hd)
-        q = apply_rope(q, pos[None], cfg.rope_theta)
-        k = apply_rope(k, pos[None], cfg.rope_theta)
+        q = _rope(q, pos[None], lo)
+        k = _rope(k, pos[None], lo)
         # this layer's pages of the row, gathered in place from the pool
         ctx_k = _layer_pages(pages["k"], rows, layer)[..., :hd]
         ctx_v = _layer_pages(pages["v"], rows, layer)[..., :hd]
@@ -874,29 +979,31 @@ def paged_prefill_chunk(cfg: ModelConfig, params, tokens, pages, table_row,
         ctx_v = ctx_v.reshape(1, n_pt * ps, cfg.n_kv_heads, hd)
         k_all = jnp.concatenate([ctx_k, k], axis=1)
         v_all = jnp.concatenate([ctx_v, v], axis=1)
-        out = masked_attention(q, k_all, v_all, kv_pos, pos, window=window)
+        out = masked_attention(q, k_all, v_all, kv_pos, pos, window=lo["window"])
         mix = jnp.einsum("bse,ed->bsd", out.reshape(1, T, -1), ap["wo"])
         if cfg.parallel_block:
-            mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h)
-            return x + mix + mlp_out, (k[0], v[0])
+            mlp_out, counts = _mlp_serve(cfg, mp, h, real, at)
+            return x + mix + mlp_out, (k[0], v[0], counts)
         x = x + mix
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        mlp_out, _ = _mlp_apply(cfg, lp["mlp"], h2)
-        return x + mlp_out, (k[0], v[0])
+        mlp_out, counts = _mlp_serve(cfg, mp, h2, real, at)
+        return x + mlp_out, (k[0], v[0], counts)
 
-    x, (k_chunk, v_chunk) = _paged_layers(cfg, params, x, block)
+    x, (k_chunk, v_chunk), counts = _paged_layers(cfg, params, x, block)
     x_last = jax.lax.dynamic_index_in_dim(x, valid_len - 1, axis=1, keepdims=False)
     x_last = rms_norm(x_last, params["final_norm"], cfg.norm_eps)
-    return _logits(cfg, params, x_last), k_chunk, v_chunk
+    return _logits(cfg, params, x_last), k_chunk, v_chunk, counts
 
 
 def _layer_pages(pool, pages, layer):
     """pool[pages, layer]: [..., ps, Hkv, W], gathered as whole rows of the
-    pool viewed as [P * L, ps, Hkv, W] — a view that costs nothing in the
-    pool's row-major layout, where a gather over two leading axes would have
-    XLA:TPU relayout the whole pool first."""
-    P, L = pool.shape[:2]
-    return pool.reshape(P * L, *pool.shape[2:])[pages * L + layer]
+    pool viewed as [P * L, ps * Hkv, W] — a view that costs nothing in the
+    pool's row-major layout, where a gather over two leading axes, or over
+    rows of [ps, Hkv, W] with fewer KV heads than a tile has rows (Mellum2's
+    4), would have XLA:TPU relayout the whole pool first."""
+    P, L, ps, hkv, w = pool.shape
+    rows = pool.reshape(P * L, ps * hkv, w)[pages * L + layer]
+    return rows.reshape(*rows.shape[:-2], ps, hkv, w)
 
 
 def _write_tokens(pages, phys, off, k, v):

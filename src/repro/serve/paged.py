@@ -410,6 +410,13 @@ class PagedEngine(_SamplerMixin):
         self.n_shared_pages = 0
         self.n_cow_copies = 0
         self.n_evictions = 0
+        # expert counters (an expert arch's decode and chunk programs count
+        # routed pairs on held experts, and held experts hit, on the
+        # device); they come back with the step's sampled tokens
+        self._counts_experts = bool(cfg.n_experts)
+        self._expert_counts = None               # device, since the last readback
+        self.n_expert_rows = 0
+        self.n_expert_hits = 0
         self._process_trace = trace.install()
 
         # warm every per-step code path on the live pool: with every table
@@ -422,7 +429,7 @@ class PagedEngine(_SamplerMixin):
             if self._decode_exe.host_mode == "static":
                 self._run_exe(self._decode_exe, (params, cache_spec, toks0),
                               pool=wpool, host_mode="dynamic")
-            chunk_logits, kc, vc = self._run_exe(
+            chunk_logits, kc, vc, chunk_counts = self._run_exe(
                 self._chunk_exe,
                 (params, self._pages, unmapped,
                  {"tokens": jnp.zeros((1, self.chunk), jnp.int32)},
@@ -438,6 +445,8 @@ class PagedEngine(_SamplerMixin):
         self._pages = self._insert_chunk(
             self._pages, unmapped, jnp.int32(0), jnp.int32(self.chunk), kc, vc)
         self._pages = self._copy_page(self._pages, jnp.int32(0), jnp.int32(0))
+        if self._counts_experts:             # the counts' sum in a mixed step
+            jax.block_until_ready(kv["experts"] + chunk_counts)
         jax.block_until_ready(self._pages)
         self._compiles_at_ready = self._process_trace.n_compiles
 
@@ -476,6 +485,11 @@ class PagedEngine(_SamplerMixin):
             "n_cold_reclaims": self.page_pool.n_cold_reclaims,
             "peak_pages": self.page_pool.peak_used,
             "peak_kv_bytes": int(self.page_pool.peak_used * self.page_bytes),
+            # (token, expert) pairs routed to the experts held, and held
+            # experts with at least one, summed over layers and calls
+            # (read back with the latest sampled tokens)
+            "n_expert_rows": self.n_expert_rows,
+            "n_expert_hits": self.n_expert_hits,
             # compilations (and compile-cache loads), on any thread of the
             # process, since this engine finished warming up: none in
             # steady serving
@@ -607,7 +621,19 @@ class PagedEngine(_SamplerMixin):
 
     def _readback(self, toks: jax.Array) -> np.ndarray:
         with span(_READBACK):
+            if self._expert_counts is None:
+                return np.asarray(toks, np.int32)
+            toks, counts = jax.device_get((toks, self._expert_counts))
+            self._expert_counts = None
+            self.n_expert_rows += int(counts[0])
+            self.n_expert_hits += int(counts[1])
             return np.asarray(toks, np.int32)
+
+    def _count_experts(self, counts: jax.Array) -> None:
+        """Add a program's expert counts on the device (expert archs)."""
+        if self._counts_experts:
+            self._expert_counts = (counts if self._expert_counts is None
+                                   else self._expert_counts + counts)
 
     def _emit(self, slot: int, token: int) -> None:
         req = self.slots[slot]
@@ -650,6 +676,7 @@ class PagedEngine(_SamplerMixin):
                 self._decode_exe, (self.params, cache, toks),
                 pool=pool, host_mode=host_mode)
         self.n_decode_steps += 1
+        self._count_experts(kv["experts"])
         sampled = self._dispatch_sample(logits)
         if join is not None:
             with span(_CHUNK_JOIN):
@@ -754,11 +781,13 @@ class PagedEngine(_SamplerMixin):
         # 4. install chunk K/V (disjoint from the decode step's tokens) and
         # activate finished prefills
         if results:
-            for (slot, task, start, T, _), (logits, kc, vc) in zip(jobs, results):
+            for (slot, task, start, T, _), (logits, kc, vc, counts) in zip(
+                    jobs, results):
                 with span(_INSERT_CHUNK):
                     self._pages = self._insert_chunk(
                         self._pages, jnp.asarray(self._table[slot]),
                         jnp.int32(start), jnp.int32(T), kc, vc)
+                self._count_experts(counts)
                 self.n_chunks += 1
                 task.pos = start + T
                 if task.pos >= task.total:

@@ -169,12 +169,14 @@ def test_paged_decode_attention(B, P, ps, n_pt, Hq, Hkv, hd, q_pos, mapped,
         jax.random.key(10), B, 2, P, ps, n_pt, Hq, Hkv, hd, q_pos, mapped, dt)
     layer = jnp.int32(1)
     ref = _seeded_ref(q, k_new, v_new, kp[:, 1], vp[:, 1], table, qp, window)
+    # the reference's None is the op's window 0: the whole context
     out = paged_decode_attention(q, k_new, v_new, kp, vp, layer, table, qp,
-                                 window=window, use_kernel=True, interpret=True)
+                                 window=window or 0, use_kernel=True,
+                                 interpret=True)
     allclose(out, ref, dt)
     # the jnp fallback path must agree too (it is what captured graphs run)
     jnp_out = paged_decode_attention(q, k_new, v_new, kp, vp, layer, table,
-                                     qp, window=window, use_kernel=False)
+                                     qp, window=window or 0, use_kernel=False)
     allclose(jnp_out, ref, dt)
 
 
@@ -331,11 +333,25 @@ def test_rglru_matches_model_recurrence():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("E,C,D,F", [(4, 64, 128, 96), (8, 32, 64, 64), (2, 128, 32, 128)])
-def test_moe_gmm(E, C, D, F, dt):
+@pytest.mark.parametrize("sizes,K,N", [
+    ((10, 0, 45, 3, 20), 128, 96),     # an empty group; a group over tiles
+    ((0, 0, 0, 0), 64, 64),            # no row in any group
+    ((96, 0, 0), 32, 128),             # one group larger than two tiles
+    ((0, 7, 0, 0, 0, 0, 0, 30), 64, 256),  # leading empty groups; rows left
+])
+def test_moe_gmm(sizes, K, N, dt):
+    """The grouped matmul of rows sorted by group, kernel in interpret mode
+    (tiles of 32 rows and 128 lanes where they divide) and the ragged_dot
+    path, against ref.py: each group's rows times its matrix, rows past
+    every group zero."""
+    M = 100
     ks = jax.random.split(jax.random.key(9), 2)
-    x = jax.random.normal(ks[0], (E, C, D), dt)
-    w = jax.random.normal(ks[1], (E, D, F), dt) * (1.0 / np.sqrt(D))
-    o1 = moe_gmm(x, w, block_c=32, block_f=32, block_d=32, interpret=True)
-    o2 = moe_gmm_ref(x, w)
-    allclose(o1, o2, dt)
+    x = jax.random.normal(ks[0], (M, K), dt)
+    w = jax.random.normal(ks[1], (len(sizes), K, N), dt) * (1.0 / np.sqrt(K))
+    gs = jnp.asarray(sizes, jnp.int32)
+    want = moe_gmm_ref(x, w, gs)
+    for use_kernel in (True, False):
+        got = moe_gmm(x, w, gs, block_m=32, block_n=128, use_kernel=use_kernel,
+                      interpret=True)
+        allclose(got, want, dt)
+        assert not np.asarray(got[sum(sizes):]).any()

@@ -54,7 +54,7 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, arch, context):
     args = (spec((batch, 1, cfg.n_heads, hd), cfg.dtype), new_kv, new_kv,
             pages, pages, spec((), jnp.int32),
             spec((batch, n_pt), jnp.int32), spec((batch,), jnp.int32))
-    fn = partial(paged_decode_attention, window=cfg.sliding_window or None,
+    fn = partial(paged_decode_attention, window=cfg.sliding_window or 0,
                  use_kernel=True, interpret=False)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -120,23 +120,19 @@ def _pool_sized_results(hlo: str, limit: int) -> list[str]:
     return found
 
 
-@pytest.mark.parametrize("max_len", [640, 1872])
-def test_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch, max_len):
-    """The paged engine's programs at h2o-danube3-4b's published widths and
-    the benchmark cells' engine shapes (32 slots, 1 024 pages of 16,
-    max_len 640 and 1 872): the decode step and the prefill chunk read the
-    pool without a copy, slice or restack of a layer's pages, and each pool
-    write — the decode install, a chunk insert, a copy-on-write — updates
-    the donated pool in place."""
+def _paged_programs(one_chip, monkeypatch, cfg, B, P, ps, T, max_len):
+    """The paged engine's five programs (decode, chunk, install, insert,
+    copy), compiled for the described chip; returns each one's HLO text and
+    memory analysis, and the pool's and one layer's pages' bytes."""
     import repro.kernels.decode_attention.ops as ops
+    import repro.kernels.moe_gmm.ops as gmm_ops
     from repro.models import transformer
     from repro.serve.step import make_paged_decode_step, make_prefill_chunk_step
 
     # the described chip is not the backend: steer the kernel path by hand
     monkeypatch.setenv("REPRO_USE_PALLAS", "1")
     monkeypatch.setattr(ops, "default_interpret", lambda: False)
-    cfg = get_config("h2o-danube3-4b")
-    B, P, ps, T = 32, 1024, 16, 64
+    monkeypatch.setattr(gmm_ops, "default_interpret", lambda: False)
     n_pt = -(-max_len // ps)
 
     def spec(shape, dtype=jnp.int32):
@@ -155,14 +151,12 @@ def test_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch, max_len):
     kv_step = spec((cfg.n_layers, B, cfg.n_kv_heads, hd), cfg.dtype)
     kv_chunk = spec((cfg.n_layers, T, cfg.n_kv_heads, hd), cfg.dtype)
     cache = {"len": spec((B,)), "table": spec((B, n_pt)), "pages": pages}
-    readers = {
+    programs = {
         "decode": (jax.jit(make_paged_decode_step(cfg)),
                    (params, cache, spec((B, 1)))),
         "chunk": (jax.jit(make_prefill_chunk_step(cfg, ps)),
                   (params, pages, spec((n_pt,)), {"tokens": spec((1, T))},
                    spec(()), spec(()))),
-    }
-    writers = {
         "install": (jax.jit(partial(transformer.paged_install_decode,
                                     page_size=ps), donate_argnums=0),
                     (pages, spec((B, n_pt)), spec((B,)), kv_step, kv_step)),
@@ -173,13 +167,56 @@ def test_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch, max_len):
         "copy": (jax.jit(transformer.paged_copy_page, donate_argnums=0),
                  (pages, spec(()), spec(()))),
     }
-    for name, (fn, args) in {**readers, **writers}.items():
+    out = {}
+    for name, (fn, args) in programs.items():
         compiled = fn.lower(*args).compile()
-        hlo = compiled.as_text()
+        out[name] = (compiled.as_text(), compiled.memory_analysis())
+    return out, pool_bytes, layer_bytes
+
+
+def _assert_pool_in_place(programs, pool_bytes, layer_bytes):
+    for name, (hlo, mem) in programs.items():
         assert not _pool_sized_results(hlo, layer_bytes), name
-        mem = compiled.memory_analysis()
-        if name in writers:
+        if name in ("install", "insert", "copy"):
             assert mem.alias_size_in_bytes == pool_bytes, name
-        if name == "decode":
-            assert "tpu_custom_call" in hlo
-            assert mem.temp_size_in_bytes < 64 * 2**20
+    hlo, mem = programs["decode"]
+    assert "tpu_custom_call" in hlo
+    assert mem.temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("max_len", [640, 1872])
+def test_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch, max_len):
+    """The paged engine's programs at h2o-danube3-4b's published widths and
+    the benchmark cells' engine shapes (32 slots, 1 024 pages of 16,
+    max_len 640 and 1 872): the decode step and the prefill chunk read the
+    pool without a copy, slice or restack of a layer's pages, and each pool
+    write — the decode install, a chunk insert, a copy-on-write — updates
+    the donated pool in place."""
+    cfg = get_config("h2o-danube3-4b")
+    _assert_pool_in_place(*_paged_programs(one_chip, monkeypatch, cfg, B=32,
+                                           P=1024, ps=16, T=64, max_len=max_len))
+
+
+def test_mellum2_paged_programs_keep_the_pool_and_experts_in_place(
+        one_chip, monkeypatch):
+    """Mellum2-12B-A2.5B at its published widths with 16 of its 64 experts
+    held, at ``mellum2.code_decode``'s engine shapes (32 slots, 4 672 pages
+    of 16, chunks of 256, max_len 2 304): as for danube3, no pool-sized
+    copy, slice or restack and every pool write in place — with 4 KV heads
+    a gather over [ps, Hkv, W] rows would relayout the pool — and neither
+    the decode step nor the chunk slices a layer's experts out of the
+    stack: the grouped matmul, a kernel of its own (beside the paged decode
+    attention in the decode step), reads them in place."""
+    cfg = get_config("mellum2-12b-a2.5b").reduced(n_experts=16, router_experts=64)
+    programs, pool_bytes, layer_bytes = _paged_programs(
+        one_chip, monkeypatch, cfg, B=32, P=4672, ps=16, T=256, max_len=2304)
+    _assert_pool_in_place(programs, pool_bytes, layer_bytes)
+    one_layer_expert = 16 * 2304 * 896 * 2           # bytes of w_gate[layer]
+    for name in ("decode", "chunk"):
+        hlo, _ = programs[name]
+        assert not _pool_sized_results(hlo, one_layer_expert // 2), name
+        kernels = set(re.findall(r"%([\w.]+?)(?:\.\d+)? = \S+ custom-call\(.*"
+                                 r'custom_call_target="tpu_custom_call"', hlo))
+        want = {"_moe_gmm"} | ({"_paged_decode_attention"} if name == "decode"
+                               else set())
+        assert want <= kernels, (name, kernels)
